@@ -28,6 +28,7 @@ from repro.comm import BudgetSpec
 from repro.comm.codecs import QuantCodec
 from repro.core import compiled
 from repro.core.compiled import compiled_session, control_sweep_run, plan_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 
 
@@ -90,6 +91,7 @@ def run(*, configs: int = 4, agents: int = 3, rounds: int = 3,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--configs", type=int, default=4)
     ap.add_argument("--agents", type=int, default=3)

@@ -12,6 +12,7 @@ import numpy as np
 from benchmarks.common import run_three_way
 from repro.core.protocol import ASCIIConfig
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.forest import RandomForest
 from repro.learners.tree import DecisionTree
 
@@ -54,6 +55,7 @@ def run(reps: int = 3, rounds: int = 8, quick: bool = True) -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--rounds", type=int, default=8)

@@ -28,6 +28,7 @@ from repro.core.protocol import ASCIIConfig, fit_single_agent_adaboost
 from repro.core.transport import oracle_bits, oracle_bits_codec
 from repro.data import synthetic
 from repro.data.synthetic import gaussian_blobs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.forest import RandomForest
 from repro.learners.logistic import LogisticRegression
 from repro.learners.mlp import MLP
@@ -273,6 +274,7 @@ def frontier(quick: bool = True, smoke: bool = False,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--frontier", action="store_true",

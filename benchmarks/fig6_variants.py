@@ -16,6 +16,7 @@ from repro.core.engine import (Protocol, SessionConfig, endpoints_for,
                                variant_setup)
 from repro.core.protocol import ASCIIConfig, fit_ensemble_adaboost
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 from repro.learners.tree import DecisionTree
 
@@ -66,6 +67,7 @@ def run(reps: int = 2, rounds: int = 6, quick: bool = True) -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--reps", type=int, default=2)
     ap.add_argument("--rounds", type=int, default=6)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.core.compiled import compiled_session, fleet_run, plan_for
 from repro.core.engine import Protocol, SessionConfig, endpoints_for
 from repro.data.synthetic import gaussian_blobs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 from repro.learners.mlp import MLP
 
@@ -104,6 +105,7 @@ def run(*, sessions: int = 8, agents: int = 3, rounds: int = 4,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--sessions", type=int, default=8)
     ap.add_argument("--agents", type=int, default=3)

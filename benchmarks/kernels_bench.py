@@ -11,6 +11,7 @@ import numpy as np
 
 from benchmarks.common import timed
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run() -> list[dict]:
@@ -73,6 +74,7 @@ def run() -> list[dict]:
 
 
 def main():
+    enable_compile_cache()
     for r in run():
         print(f"{r['kernel']},{r['shape']},err={r['max_err']:.2e},"
               f"us_interp={r['us_pallas_interp']:.0f},us_ref={r['us_ref']:.0f}")

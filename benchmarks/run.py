@@ -6,12 +6,15 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _section(name):
     print(f"\n# === {name} ===", flush=True)
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale sizes/replications (slow)")

@@ -40,6 +40,7 @@ from repro.core.engine import (MeteredTransport, Protocol, SessionConfig,
                                endpoints_for)
 from repro.data import synthetic
 from repro.data.partition import train_test_split, vertical_split
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 from repro.scenarios import PRESETS, make_variant
 
@@ -149,6 +150,7 @@ def run(*, rounds: int = 4, steps: int = 80, n: int = 240,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--rounds", type=int, default=4)
     ap.add_argument("--steps", type=int, default=80)

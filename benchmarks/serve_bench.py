@@ -34,6 +34,7 @@ from repro.comm.codecs import make_codec
 from repro.core import compiled
 from repro.core.engine import (MeteredTransport, Protocol, SessionConfig,
                                endpoints_for)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 from repro.serve import ServeEngine
 from repro.telemetry.registry import MetricsRegistry
@@ -160,6 +161,7 @@ def run(*, sessions: int = 8, requests: int = 64, agents: int = 3,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--sessions", type=int, default=8)
     ap.add_argument("--requests", type=int, default=64)

@@ -47,6 +47,7 @@ from repro.core.engine import Protocol, SessionConfig, endpoints_for
 from repro.core.transport import TransportLog
 from repro.data import synthetic
 from repro.data.partition import train_test_split, vertical_split
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 from repro.telemetry import Telemetry
 from repro.telemetry.check import validate_file
@@ -206,6 +207,7 @@ def check(*, max_overhead=1.05, repeats=5, out="BENCH_telemetry.json",
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--backend", default="compiled",
                     choices=["eager", "compiled"])

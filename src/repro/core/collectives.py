@@ -21,24 +21,19 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
-from repro.sharding.context import shard_map
 
 
 def interchange_step(w_shard: jnp.ndarray, r_shard: jnp.ndarray,
                      alpha: jnp.ndarray, *, agent_axis: str,
-                     data_axis: str | None,
-                     agent_size: int | None = None) -> jnp.ndarray:
+                     data_axis: str | None) -> jnp.ndarray:
     """One hop of Algorithm 1 (eqs. 10/12) on a sharded score vector.
 
     w_shard/r_shard: this device's slice of the length-n score/reward.
     Returns the slice this device holds *for the next agent* (ring permute).
-    ``agent_size`` is the ring length; required on JAX versions without
-    ``jax.lax.axis_size`` (the perm list must be static).
     """
     w_new = ops.ignorance_update(w_shard, r_shard, alpha,
                                  axis_name=data_axis)
-    if agent_size is None:
-        agent_size = jax.lax.axis_size(agent_axis)
+    agent_size = jax.lax.axis_size(agent_axis)
     perm = [(i, (i + 1) % agent_size) for i in range(agent_size)]
     return jax.lax.ppermute(w_new, agent_axis, perm)
 
@@ -52,15 +47,13 @@ def make_ring_interchange(mesh, *, agent_axis: str = "agent",
     Output: w' [M, n] where agent (m+1) now holds agent m's updated score.
     """
 
-    size = mesh.shape[agent_axis]
-
     def step(w, r, alpha):
         out = interchange_step(w[0], r[0], alpha[0], agent_axis=agent_axis,
-                               data_axis=data_axis, agent_size=size)
+                               data_axis=data_axis)
         return out[None]
 
-    return shard_map(
+    return jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(agent_axis, data_axis), P(agent_axis, data_axis),
                   P(agent_axis)),
-        out_specs=P(agent_axis, data_axis))
+        out_specs=P(agent_axis, data_axis), check_vma=False)

@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import scores
-from repro.kernels.ignorance import tiles_evenly
 
 PyTree = Any
 
@@ -73,11 +72,10 @@ class SessionPlan:
     alpha_cap: float = 20.0
     exact_reweight: bool = False
     # Run eqs. (10)/(12) through the fused Pallas kernel
-    # (kernels.ignorance.ignorance_update_unnormalized) when the score
-    # length tiles evenly; False forces the plain jnp formula everywhere.
-    # The two are bit-identical for n <= the kernel tile (1024); above it
-    # the tiled partial-sum reduction can differ in the last ulp, which is
-    # why Protocol._fit_compiled derives this flag from the transport
+    # (kernels.ignorance.ignorance_update_unnormalized) at any score length;
+    # False forces the plain jnp formula.  The kernel's lane-tiled
+    # partial-sum reduction can differ from jnp.sum in the last ulp, which
+    # is why Protocol._fit_compiled derives this flag from the transport
     # (kernel iff MeshRingTransport) instead of taking the default.
     use_kernel: bool = True
     # Pallas interpret-mode override for the kernel (None = resolve by
@@ -233,14 +231,14 @@ def plan_for(learners: Sequence, num_classes: int, *, max_rounds: int = 20,
 
 
 # ==================================================================== lowering
-def _make_reweight(plan: SessionPlan, n: int):
-    """Pick the eqs.-(10)/(12) implementation for score length n: the fused
-    Pallas kernel when the tiling divides evenly (interpret mode off-TPU),
-    else the pure-jnp formula — both bit-identical reductions for n <= bn."""
+def _make_reweight(plan: SessionPlan):
+    """Pick the eqs.-(10)/(12) implementation: the exact reweight, the
+    fused Pallas kernel (interpret mode off-TPU), or the pure-jnp
+    formula."""
     if plan.exact_reweight:
         k = plan.num_classes
         return lambda w, r, a: scores.ignorance_update_exact(w, r, a, k)
-    if plan.use_kernel and tiles_evenly(n):
+    if plan.use_kernel:
         from repro.kernels import ops
         return lambda w, r, a: ops.ignorance_update(
             w, r, a, interpret=plan.kernel_interpret)
@@ -368,7 +366,7 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
         classes = classes.astype(jnp.int32)
         n = classes.shape[0]
         onehot = jax.nn.one_hot(classes, k)
-        reweight = _make_reweight(plan, n)
+        reweight = _make_reweight(plan)
         w0 = scores.init_ignorance(n)
         ones = jnp.ones((n,), jnp.float32)
         if scheduler is not None:
@@ -976,7 +974,6 @@ def _fleet_program(plan: SessionPlan, feature_shapes: tuple,
     if axis_name is None:
         return jax.jit(vf)
 
-    from repro.sharding.context import shard_map  # version shim
     P = jax.sharding.PartitionSpec
 
     def sharded(keys, Xs, classes):
@@ -986,8 +983,9 @@ def _fleet_program(plan: SessionPlan, feature_shapes: tuple,
         in_specs = (spec_b, tuple(spec_data for _ in Xs), spec_data)
         out_specs = jax.tree.map(lambda _: spec_b,
                                  jax.eval_shape(vf, keys, Xs, classes))
-        return shard_map(vf, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)(keys, Xs, classes)
+        return jax.shard_map(vf, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs,
+                             check_vma=False)(keys, Xs, classes)
 
     return jax.jit(sharded)
 

@@ -528,12 +528,6 @@ class MeshRingTransport(Transport):
         if not standard:
             return reweight(w, r, alpha)
         from repro.kernels import ops
-        from repro.kernels.ignorance import tiles_evenly
-        if not tiles_evenly(w.shape[0]):
-            # score length doesn't tile the kernel grid; host formula
-            # (same shared predicate the compiled backend's _make_reweight
-            # checks, so eager and compiled stay in lockstep at any n)
-            return reweight(w, r, alpha)
         return ops.ignorance_update(w, r, jnp.asarray(alpha, w.dtype),
                                     interpret=self.interpret)
 
@@ -1548,9 +1542,8 @@ class Protocol:
             alpha_cap=cfg.alpha_cap, exact_reweight=cfg.exact_reweight,
             # mirror the eager transport's update implementation: mesh-ring
             # runs the fused Pallas kernel (with its configured interpret
-            # mode), the host transports the jnp formula — so the pin holds
-            # at any score length (at n <= bn the two are bit-identical
-            # anyway)
+            # mode), the host transports the jnp formula — so both backends
+            # reduce the normalizer the same way at any score length
             use_kernel=isinstance(self.transport, MeshRingTransport),
             kernel_interpret=getattr(self.transport, "interpret", None),
             # the wire channel rides the scan: same codec/privacy/budget

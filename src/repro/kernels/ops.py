@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU build box kernels run in interpret mode (the Pallas body
-executed in Python); on TPU pass interpret=False (default resolves by
-backend).  ``weighted_ce`` wires the forward/backward kernels into a
+``interpret=None`` resolves by backend: compiled Mosaic kernels on a TPU,
+interpret mode (the Pallas body executed as jnp) on the CPU, where the
+tests run.  ``weighted_ce`` wires the forward/backward kernels into a
 custom_vjp so the fused loss is a drop-in for training.
 """
 from __future__ import annotations
@@ -59,8 +59,9 @@ def flash_attention(q, k, v, *, causal=True, window=None,
 # --------------------------------------------------------- ignorance update
 def ignorance_update(w, r, alpha, *, axis_name: str | None = None,
                      interpret: bool | None = None):
-    """Fused eqs. (10)/(12).  Under shard_map pass axis_name to make the
-    normalizer global across the data-sharded score vector."""
+    """Fused eqs. (10)/(12) at any score length (the kernel pads a ragged
+    tail itself).  Under shard_map pass axis_name to make the normalizer
+    global across the data-sharded score vector."""
     interp = _default_interpret() if interpret is None else interpret
     w_new, psums = _ig.ignorance_update_unnormalized(w, r, alpha,
                                                      interpret=interp)
@@ -89,21 +90,20 @@ def quantize_dequant_block(x, u, qmax, *, bn: int = 1024,
     return _q.quantize_dequant_block(x, u, qmax, bn=bn, interpret=interp)
 
 
-def pack_int4(q, *, bn: int = 1024, interpret: bool | None = None):
+def pack_int4(q, *, interpret: bool | None = None):
     """Pack int8-carried int4 values into real 4-bit wire bytes: two
     sign-extended nibbles per int8 byte (flat, ceil(numel/2) long) — the
     int4 codec's actual wire array (repro.comm.codecs)."""
     interp = _default_interpret() if interpret is None else interpret
     from repro.kernels import quantize as _q
-    return _q.pack_int4(q, bn=bn, interpret=interp)
+    return _q.pack_int4(q, interpret=interp)
 
 
-def unpack_int4(packed, n: int, *, bn: int = 1024,
-                interpret: bool | None = None):
+def unpack_int4(packed, n: int, *, interpret: bool | None = None):
     """Inverse of :func:`pack_int4`: n int8-carried int4 values (flat)."""
     interp = _default_interpret() if interpret is None else interpret
     from repro.kernels import quantize as _q
-    return _q.unpack_int4(packed, n, bn=bn, interpret=interp)
+    return _q.unpack_int4(packed, n, interpret=interp)
 
 
 def flash_decode(q, k, v, pos, *, k_scale=None, v_scale=None, window=None,

@@ -31,6 +31,7 @@ from repro.core.engine import (MeteredTransport, Protocol, SessionConfig,
                                endpoints_for)
 from repro.data import synthetic
 from repro.data.partition import train_test_split, vertical_split
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 from repro.serve import AdmissionController, AdmissionPolicy, ServeEngine
 from repro.telemetry import Telemetry
@@ -66,6 +67,7 @@ def fit_fleet(args, key, Xtr, ctr, num_classes, telemetry=None):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dataset", default="blob3",
                     choices=["blob3", "blob4", "blob6"])

@@ -32,6 +32,7 @@ from repro.core.engine import (InProcessTransport, MeshRingTransport,
                                endpoints_for, variant_setup)
 from repro.data.partition import train_test_split, vertical_split
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 from repro.learners.mlp import MLP
 from repro.learners.tree import DecisionTree
@@ -119,6 +120,7 @@ def _finish_telemetry(args, telemetry, transport, dash=None):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dataset", default="blob3", choices=sorted(DATASETS))
     ap.add_argument("--n", type=int, default=600)

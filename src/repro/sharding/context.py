@@ -1,31 +1,11 @@
 """Trace-time mesh context: lets model-internal shard_map blocks (the
 ep_a2a MoE) see the mesh the launcher is lowering under, without threading
-a Mesh handle through every model signature.  Also home of the shard_map
-version shim used by every shard_map call site."""
+a Mesh handle through every model signature."""
 from __future__ import annotations
 
 import contextlib
 
-import jax
-
 _CURRENT_MESH = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map if present (newer JAX), else the experimental home —
-    same semantics; replication checking disabled either way (the kwarg is
-    `check_vma` on new JAX, `check_rep` on the versions before — including a
-    window where jax.shard_map exists but only takes `check_rep`)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
 
 
 @contextlib.contextmanager

@@ -32,7 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.models.moe import router_topk
-from repro.sharding.context import current_mesh, shard_map
+from repro.sharding.context import current_mesh
 
 
 def _round_up(x: int, m: int) -> int:
@@ -111,9 +111,9 @@ def moe_apply_ep_a2a(params, x: jnp.ndarray, cfg: ArchConfig):
         aux = jax.lax.pmean(aux, data_ax)
         return out.reshape(b_loc, s, d).astype(x_loc.dtype), aux
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None), w_spec, w_spec, wo_spec),
-        out_specs=(P(dp, None, None), P()))
+        out_specs=(P(dp, None, None), P()), check_vma=False)
     return mapped(x, params["router"], params["wi_gate"], params["wi_up"],
                   params["wo"])

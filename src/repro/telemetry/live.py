@@ -67,12 +67,11 @@ def installed(sink: "LiveSink | None"):
     try:
         yield
     finally:
+        import jax
         try:
-            import jax
             jax.effects_barrier()
-        except Exception:
-            pass
-        _SINK = prev
+        finally:
+            _SINK = prev
 
 
 # ----------------------------------------------------- traced-side helpers

@@ -1,12 +1,14 @@
 """Wire channel through both engine backends: eager and compiled must
-produce bit-identical trajectories AND identical encoded-bit ledgers for
-every codec, the budget must degrade/defer identically, byte accounting must
+produce the same trajectories (floats to the tolerance of two separately
+compiled programs, tests/program_tolerance.py) AND identical encoded-bit
+ledgers for every codec, the budget must degrade/defer identically, byte accounting must
 stay consistent under agent dropout and late joins, and codec state must
 checkpoint/resume exactly."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from program_tolerance import assert_floats_close, assert_history_close
 
 from repro.comm import (BudgetSpec, BudgetedTransport, GaussianMechanism,
                         make_codec)
@@ -44,10 +46,9 @@ def _fit(blob, transport, backend, rounds=3, steps=40, **cfg_kw):
 def _assert_identical(eager, comp, Xte):
     assert [(c.agent, c.round) for c in eager.components] == \
            [(c.agent, c.round) for c in comp.components]
-    np.testing.assert_array_equal(
-        np.asarray([c.alpha for c in eager.components]),
-        np.asarray([c.alpha for c in comp.components]))
-    assert eager.history == comp.history
+    assert_floats_close([c.alpha for c in eager.components],
+                        [c.alpha for c in comp.components])
+    assert_history_close(eager.history, comp.history)
     np.testing.assert_array_equal(np.asarray(eager.predict(Xte)),
                                   np.asarray(comp.predict(Xte)))
 
@@ -348,7 +349,5 @@ def test_quant_sweep_matches_per_config_runs(blob):
                             jnp.asarray([127.0, 7.0]))
     for row, plan in ((0, plan8), (1, plan4)):
         single = compiled_session(plan, key, Xtr, ctr)
-        np.testing.assert_array_equal(np.asarray(sweep.alphas[row]),
-                                      np.asarray(single.alphas))
-        np.testing.assert_array_equal(np.asarray(sweep.w[row]),
-                                      np.asarray(single.w))
+        assert_floats_close(sweep.alphas[row], single.alphas)
+        assert_floats_close(sweep.w[row], single.w)

@@ -19,10 +19,8 @@ from repro.sharding import rules
 # An abstract 16x16 mesh for spec validation only (no devices needed).
 from jax.sharding import AbstractMesh
 
-# AbstractMesh takes a ((name, size), ...) shape tuple on this JAX version
-# (the old (dims, names) signature was removed).
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
