@@ -231,6 +231,25 @@ def plan_for(learners: Sequence, num_classes: int, *, max_rounds: int = 20,
 
 
 # ==================================================================== lowering
+#: Trace-entry counters keyed by program family (``session``,
+#: ``async_session``, ``serve``, ``serve_batch``, ``fleet``, ``sweep``,
+#: ``sweep_serve``, ``control_sweep``): one increment each time a family's
+#: program is traced, none per call.  A correctly cached program traces
+#: once however often it runs, and a sweep once however many configs it
+#: vmaps over; ``Telemetry.sync_gauges`` exports the totals.
+TRACE_COUNTS: dict = {}
+
+
+def _counted(family: str, fn):
+    """``fn`` counting its traces under ``family`` in :data:`TRACE_COUNTS`
+    (what ``jax.jit`` is handed; its body runs at trace time only)."""
+    @functools.wraps(fn)
+    def traced(*args):
+        TRACE_COUNTS[family] = TRACE_COUNTS.get(family, 0) + 1
+        return fn(*args)
+    return traced
+
+
 def _make_reweight(plan: SessionPlan):
     """Pick the eqs.-(10)/(12) implementation: the exact reweight, the
     fused Pallas kernel (interpret mode off-TPU), or the pure-jnp
@@ -449,119 +468,124 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
                                       X_j, onehot, w)
                     r = (core.predict(params, X_j) == classes
                          ).astype(jnp.float32)
-                u_in = ones if (j == 0 or not plan.upstream) else u
-                a, rbar = scores.model_weight(w, r, k, u=u_in,
-                                              alpha_cap=plan.alpha_cap)
-                executed = jnp.logical_not(stopped)
-                if plan.stop_on_negative_alpha:
-                    trigger = executed & (a <= 0)   # Algorithm 1, line 8
-                else:
-                    trigger = jnp.zeros((), bool)
-                valid = executed & jnp.logical_not(trigger)
-                if scheduler is not None and scheduler.use_reward:
-                    # the observed-reward EMA advances on every slot the
-                    # eager loop reaches (observe runs before the stop
-                    # check), through the shared f32 update
-                    prev = carry["ema"][src]
-                    upd = reward_ema_update(scheduler.reward_smoothing,
-                                            prev, rbar,
-                                            ~carry["seen"][src])
-                    carry["ema"] = carry["ema"].at[src].set(
-                        jnp.where(executed, upd, prev))
-                    carry["seen"] = carry["seen"].at[src].set(
-                        carry["seen"][src] | executed)
-                # Only a component-producing slot advances u and w — the
-                # eager loop breaks before touching them on a stop trigger,
-                # and never reaches them once stopped.
-                u = jnp.where(valid,
-                              scores.upstream_factor_update(u, a, r, k), u)
-                w_upd = reweight(w, r, a)
+                # eq. (13) weight, the stop rule, eqs. (10)/(12): a scope
+                # of their own beside the hop's, as is the channel below
+                with jax.named_scope(f"ascii_update_{j}"):
+                    u_in = ones if (j == 0 or not plan.upstream) else u
+                    a, rbar = scores.model_weight(w, r, k, u=u_in,
+                                                  alpha_cap=plan.alpha_cap)
+                    executed = jnp.logical_not(stopped)
+                    if plan.stop_on_negative_alpha:
+                        trigger = executed & (a <= 0)   # Algorithm 1, line 8
+                    else:
+                        trigger = jnp.zeros((), bool)
+                    valid = executed & jnp.logical_not(trigger)
+                    if scheduler is not None and scheduler.use_reward:
+                        # the observed-reward EMA advances on every slot the
+                        # eager loop reaches (observe runs before the stop
+                        # check), through the shared f32 update
+                        prev = carry["ema"][src]
+                        upd = reward_ema_update(scheduler.reward_smoothing,
+                                                prev, rbar,
+                                                ~carry["seen"][src])
+                        carry["ema"] = carry["ema"].at[src].set(
+                            jnp.where(executed, upd, prev))
+                        carry["seen"] = carry["seen"].at[src].set(
+                            carry["seen"][src] | executed)
+                    # Only a component-producing slot advances u and w — the
+                    # eager loop breaks before touching them on a stop trigger,
+                    # and never reaches them once stopped.
+                    u = jnp.where(valid,
+                                  scores.upstream_factor_update(u, a, r, k), u)
+                    w_upd = reweight(w, r, a)
 
                 if not has_channel:
                     sent = valid
                     rung = jnp.where(sent, 0, -1).astype(jnp.int32)
                     w = jnp.where(valid, w_upd, w)
                 else:
-                    # ---- the wire: controller/budget rung choice, DP
-                    # noise, codec — the same decision rule and traced
-                    # channel the eager transports run
-                    # (Transport._controller_rung / BudgetSpec.choose /
-                    # channel_apply)
-                    if controller is not None:
-                        # branchless adaptive rung from (receiver's stale
-                        # vector, outgoing vector); the EMA advances on
-                        # every slot the eager loop reaches an interchange
-                        # for.  cuts/beta are None outside control_arg
-                        # sweeps — the controller then uses its static
-                        # thresholds, unchanged bit for bit.
-                        c_rung, ctrl_new = controller.step(w, w_upd,
-                                                           carry["ctrl"],
-                                                           cuts=cuts,
-                                                           beta=beta)
-                        carry["ctrl"] = jnp.where(valid, ctrl_new,
-                                                  carry["ctrl"])
-                    if budget is not None:
-                        cap_session = (session_cap if control_arg
-                                       else budget.session_bits)
-                        cap_link = (link_cap if control_arg
-                                    else budget.link_bits)
-                        rem = jnp.asarray(_INT32_MAX, jnp.int32)
-                        if cap_session is not None:
-                            rem_s = (jnp.asarray(cap_session,
-                                                 jnp.int32) - carry["spent"])
-                            rem = jnp.minimum(rem, rem_s)
-                        if cap_link is not None:
-                            link_spent_j = (carry["link"][src, dst_agent]
-                                            if scheduler is not None
-                                            else carry["link"][j])
-                            rem = jnp.minimum(
-                                rem, jnp.asarray(cap_link, jnp.int32)
-                                - link_spent_j)
-                        # the controller rung is a floor on the walk:
-                        # never finer, budget may go coarser
-                        rung = ladder_walk(
-                            costs, rem,
-                            floor=c_rung if controller is not None else None)
-                        sendable = rung >= 0
-                    elif controller is not None:
-                        rung = c_rung
-                        sendable = jnp.ones((), bool)
-                    else:
-                        rung = jnp.asarray(0, jnp.int32)
-                        sendable = jnp.ones((), bool)
-                    state_j = carry["resid"][src] if stateful else None
-                    # privacy noise is rung-independent (same key, same
-                    # input): apply it once, then codec-only roundtrips per
-                    # rung — the per-stage key folds inside channel_apply
-                    # depend only on `sub`, so this decomposition is
-                    # bit-identical to the eager fused channel
-                    w_noised, _ = channel_apply(None, privacy, w_upd, sub,
-                                                None)
-                    pairs = [channel_apply(c, None, w_noised, sub, state_j,
-                                           qmax=qmax) for c in ladder]
-                    w_chan = rung_select(rung, [p[0] for p in pairs], w_upd)
-                    sent = valid & sendable
-                    w = jnp.where(sent, w_chan, w)
-                    if stateful:
-                        # error-feedback residuals are per *sender* (the
-                        # eager engine keys codec_state by src name)
-                        carry["resid"] = carry["resid"].at[src].set(
-                            jnp.where(sent, pairs[0][1], state_j))
-                    if budget is not None:
-                        cost = jnp.select(
-                            [rung == i for i in range(len(ladder))],
-                            list(costs), jnp.asarray(0, jnp.int32))
-                        add = jnp.where(sent, cost, 0)
-                        carry["spent"] = carry["spent"] + add
-                        if scheduler is not None:
-                            carry["link"] = carry["link"].at[
-                                src, dst_agent].add(add)
+                    with jax.named_scope(f"ascii_channel_{j}"):
+                        # ---- the wire: controller/budget rung choice, DP
+                        # noise, codec — the same decision rule and traced
+                        # channel the eager transports run
+                        # (Transport._controller_rung / BudgetSpec.choose /
+                        # channel_apply)
+                        if controller is not None:
+                            # branchless adaptive rung from (receiver's stale
+                            # vector, outgoing vector); the EMA advances on
+                            # every slot the eager loop reaches an interchange
+                            # for.  cuts/beta are None outside control_arg
+                            # sweeps — the controller then uses its static
+                            # thresholds, unchanged bit for bit.
+                            c_rung, ctrl_new = controller.step(w, w_upd,
+                                                               carry["ctrl"],
+                                                               cuts=cuts,
+                                                               beta=beta)
+                            carry["ctrl"] = jnp.where(valid, ctrl_new,
+                                                      carry["ctrl"])
+                        if budget is not None:
+                            cap_session = (session_cap if control_arg
+                                           else budget.session_bits)
+                            cap_link = (link_cap if control_arg
+                                        else budget.link_bits)
+                            rem = jnp.asarray(_INT32_MAX, jnp.int32)
+                            if cap_session is not None:
+                                rem_s = (jnp.asarray(cap_session, jnp.int32)
+                                         - carry["spent"])
+                                rem = jnp.minimum(rem, rem_s)
+                            if cap_link is not None:
+                                link_spent_j = (carry["link"][src, dst_agent]
+                                                if scheduler is not None
+                                                else carry["link"][j])
+                                rem = jnp.minimum(
+                                    rem, jnp.asarray(cap_link, jnp.int32)
+                                    - link_spent_j)
+                            # the controller rung is a floor on the walk:
+                            # never finer, budget may go coarser
+                            rung = ladder_walk(
+                                costs, rem, floor=(c_rung if controller
+                                                   is not None else None))
+                            sendable = rung >= 0
+                        elif controller is not None:
+                            rung = c_rung
+                            sendable = jnp.ones((), bool)
                         else:
-                            carry["link"] = carry["link"].at[j].add(add)
-                        if cap_session is not None:
-                            carry["exhausted"] = carry["exhausted"] | (
-                                valid & (rem_s < min_cost))
-                    rung = jnp.where(sent, rung, -1)
+                            rung = jnp.asarray(0, jnp.int32)
+                            sendable = jnp.ones((), bool)
+                        state_j = carry["resid"][src] if stateful else None
+                        # privacy noise is rung-independent (same key, same
+                        # input): apply it once, then codec-only roundtrips per
+                        # rung — the per-stage key folds inside channel_apply
+                        # depend only on `sub`, so this decomposition is
+                        # bit-identical to the eager fused channel
+                        w_noised, _ = channel_apply(None, privacy, w_upd, sub,
+                                                    None)
+                        pairs = [channel_apply(c, None, w_noised, sub, state_j,
+                                               qmax=qmax) for c in ladder]
+                        w_chan = rung_select(rung, [p[0] for p in pairs],
+                                             w_upd)
+                        sent = valid & sendable
+                        w = jnp.where(sent, w_chan, w)
+                        if stateful:
+                            # error-feedback residuals are per *sender* (the
+                            # eager engine keys codec_state by src name)
+                            carry["resid"] = carry["resid"].at[src].set(
+                                jnp.where(sent, pairs[0][1], state_j))
+                        if budget is not None:
+                            cost = jnp.select(
+                                [rung == i for i in range(len(ladder))],
+                                list(costs), jnp.asarray(0, jnp.int32))
+                            add = jnp.where(sent, cost, 0)
+                            carry["spent"] = carry["spent"] + add
+                            if scheduler is not None:
+                                carry["link"] = carry["link"].at[
+                                    src, dst_agent].add(add)
+                            else:
+                                carry["link"] = carry["link"].at[j].add(add)
+                            if cap_session is not None:
+                                carry["exhausted"] = carry["exhausted"] | (
+                                    valid & (rem_s < min_cost))
+                        rung = jnp.where(sent, rung, -1)
                 if scheduler is not None \
                         and scheduler.spend_signal == "wire":
                     # per-sender metered-ledger tally (ignorance wire bits
@@ -653,7 +677,8 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
 @functools.lru_cache(maxsize=64)
 def _session_program(plan: SessionPlan, feature_shapes: tuple,
                      live: bool = False):
-    return jax.jit(make_session_fn(plan, feature_shapes, live=live))
+    return jax.jit(_counted("session",
+                            make_session_fn(plan, feature_shapes, live=live)))
 
 
 def compiled_session(plan: SessionPlan, key: jax.Array,
@@ -919,7 +944,8 @@ def make_async_session_fn(plan: SessionPlan, feature_shapes: tuple,
 @functools.lru_cache(maxsize=64)
 def _async_session_program(plan: SessionPlan, feature_shapes: tuple,
                            live: bool = False):
-    return jax.jit(make_async_session_fn(plan, feature_shapes, live=live))
+    return jax.jit(_counted("async_session", make_async_session_fn(
+        plan, feature_shapes, live=live)))
 
 
 def async_session(plan: SessionPlan, key: jax.Array,
@@ -972,7 +998,7 @@ def _fleet_program(plan: SessionPlan, feature_shapes: tuple,
     data_ax = 0 if data_batched else None
     vf = jax.vmap(fn, in_axes=(0, data_ax, data_ax))
     if axis_name is None:
-        return jax.jit(vf)
+        return jax.jit(_counted("fleet", vf))
 
     P = jax.sharding.PartitionSpec
 
@@ -987,7 +1013,7 @@ def _fleet_program(plan: SessionPlan, feature_shapes: tuple,
                              out_specs=out_specs,
                              check_vma=False)(keys, Xs, classes)
 
-    return jax.jit(sharded)
+    return jax.jit(_counted("fleet", sharded))
 
 
 def fleet_run(plan: SessionPlan, keys: jax.Array, Xs: Sequence[jnp.ndarray],
@@ -1219,7 +1245,8 @@ def make_serve_fn(plan: SessionPlan, feature_shapes: tuple,
 @functools.lru_cache(maxsize=64)
 def _serve_program(plan: SessionPlan, feature_shapes: tuple,
                    live: bool = False):
-    return jax.jit(make_serve_fn(plan, feature_shapes, live=live))
+    return jax.jit(_counted("serve",
+                            make_serve_fn(plan, feature_shapes, live=live)))
 
 
 def serve_session(plan: SessionPlan, result: SessionResult, key,
@@ -1288,7 +1315,7 @@ def _serve_batch_program(plan: SessionPlan, feature_shapes: tuple,
         return jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, 0, 0, 0))(
             keys, Xs, params, alphas, valid, rem_s, rem_l, deliver)
 
-    return jax.jit(run)
+    return jax.jit(_counted("serve_batch", run))
 
 
 def serve_batch(plan: SessionPlan, slots, *,
@@ -1320,7 +1347,8 @@ def serve_batch(plan: SessionPlan, slots, *,
 @functools.lru_cache(maxsize=64)
 def _sweep_program(plan: SessionPlan, feature_shapes: tuple):
     fn = make_session_fn(plan, feature_shapes, qmax_arg=True)
-    return jax.jit(jax.vmap(fn, in_axes=(0, None, None, 0)))
+    return jax.jit(_counted("sweep",
+                            jax.vmap(fn, in_axes=(0, None, None, 0))))
 
 
 @functools.lru_cache(maxsize=64)
@@ -1339,7 +1367,8 @@ def _sweep_serve_program(plan: SessionPlan, feature_shapes: tuple):
                     jnp.ones((num,), bool), qmax)
         return res, serve
 
-    return jax.jit(jax.vmap(run_one, in_axes=(0, None, None, 0, None)))
+    return jax.jit(_counted("sweep_serve", jax.vmap(
+        run_one, in_axes=(0, None, None, 0, None))))
 
 
 def quant_sweep_run(plan: SessionPlan, keys: jax.Array,
@@ -1374,24 +1403,12 @@ def quant_sweep_run(plan: SessionPlan, keys: jax.Array,
 
 
 # ============================================================== control sweep
-#: Trace-entry counters keyed by program family — CI's compile-count
-#: assertion reads these: a correctly cached sweep traces exactly once no
-#: matter how many configs it vmaps over.
-TRACE_COUNTS: dict = {}
-
-
 @functools.lru_cache(maxsize=64)
 def _control_sweep_program(plan: SessionPlan, feature_shapes: tuple,
                            live: bool = False):
     fn = make_session_fn(plan, feature_shapes, control_arg=True, live=live)
-
-    def counted(key, Xs, classes, cuts, beta, session_cap, link_cap):
-        # runs at trace time only: one increment per compile, not per config
-        TRACE_COUNTS["control_sweep"] = \
-            TRACE_COUNTS.get("control_sweep", 0) + 1
-        return fn(key, Xs, classes, cuts, beta, session_cap, link_cap)
-
-    return jax.jit(jax.vmap(counted, in_axes=(0, None, None, 0, 0, 0, 0)))
+    return jax.jit(_counted("control_sweep", jax.vmap(
+        fn, in_axes=(0, None, None, 0, 0, 0, 0))))
 
 
 def control_sweep_run(plan: SessionPlan, keys: jax.Array,

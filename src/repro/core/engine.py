@@ -1460,12 +1460,15 @@ class Protocol:
     def fit(self, key: jax.Array, endpoints: Sequence[AgentEndpoint],
             classes: jnp.ndarray, validation=None) -> FittedASCII:
         self._fit_key = key
-        if self.backend == "compiled":
-            return self._fit_compiled(key, endpoints, classes, validation)
-        session = self.start(key, endpoints, classes, validation=validation)
-        session.run()
-        self._session = session
-        return session.fitted()
+        with self._span("fit", backend=self.backend, agents=len(endpoints)):
+            if self.backend == "compiled":
+                return self._fit_compiled(key, endpoints, classes,
+                                          validation)
+            session = self.start(key, endpoints, classes,
+                                 validation=validation)
+            session.run()
+            self._session = session
+            return session.fitted()
 
     # ---- compiled backend ---------------------------------------------------
     def _span(self, name: str, step: int | None = None, **attrs):
@@ -1492,21 +1495,85 @@ class Protocol:
                       classes: jnp.ndarray, validation) -> FittedASCII:
         """One-program execution of the whole run (core/compiled.py), with
         the transport ledger replayed afterwards so Fig.-4 metering is
-        byte-identical to the eager path."""
+        byte-identical to the eager path.
+
+        Under telemetry the ASCII run is four spans below ``fit``: ``plan``
+        (transport attach, scheduler bind, ``plan_for``), ``session`` (the
+        fenced compiled call; ``traced`` counts the programs it traced),
+        ``extract`` (the fitted ensemble and the agent-major view; its
+        ``components`` and ``leaves``, the eager parameter slices), and
+        ``replay`` (the ledger; ``messages``, the entries it books on a
+        metered transport)."""
         from repro.core import compiled
-        cfg = self.cfg
-        if self.telemetry is not None:
-            # attach before any booking: the replay walk below (and the
-            # variant lowerings' replays) then emit into the registry
-            # through the same TransportLog/accountant hooks the eager
-            # path uses
-            self.telemetry.attach_transport(self.transport)
         if not isinstance(self.variant, ASCIIVariant):
+            self._attach_telemetry()
             # protocol variants own their lowering (repro.scenarios.compiled
             # lowers FedAvg's homogeneous round into a lax.scan); the engine
             # stays variant-agnostic
             return self.variant.fit_compiled(self, key, endpoints, classes,
                                              validation)
+        with self._span("plan"):
+            self._attach_telemetry()
+            plan = self._compiled_plan(endpoints, validation)
+        live_sink = self._live_sink()
+        stale = isinstance(plan.scheduler, compiled.AsyncStalePlan)
+        run = compiled.async_session if stale else compiled.compiled_session
+        with self._span("session", backend="compiled",
+                        agents=len(endpoints)) as span:
+            traced = sum(compiled.TRACE_COUNTS.values())
+            # the fence closes the span at computation-done, not at
+            # async-dispatch enqueue — timing only, values untouched
+            with live_installed(live_sink):
+                result = self._fence(run(
+                    plan, key, tuple(ep.X for ep in endpoints), classes,
+                    live=live_sink is not None))
+            if span is not None:
+                span.attrs["traced"] = (sum(compiled.TRACE_COUNTS.values())
+                                        - traced)
+        learners = [ep.learner for ep in endpoints]
+        with self._span("extract") as span:
+            if stale:
+                fitted = compiled.fitted_from_async_result(plan, result,
+                                                           learners)
+                kept = result
+            else:
+                fitted = compiled.fitted_from_result(plan, result, learners)
+                # the serve path indexes per-agent state positionally:
+                # store the agent-major view (identity re-collection for
+                # sequential plans)
+                kept = compiled.agent_major_result(result)
+            if span is not None:
+                leaves = sum(len(jax.tree.leaves(c.params))
+                             for c in fitted.components)
+                if kept is not result:   # one slice per (round, leaf)
+                    leaves += (result.alphas.shape[0]
+                               * len(jax.tree.leaves(result.params)))
+                span.attrs.update(components=len(fitted.components),
+                                  leaves=leaves)
+        replay = self._replay_traffic_async if stale else self._replay_traffic
+        log = getattr(self.transport, "log", None)
+        with self._span("replay", backend="compiled") as span:
+            booked = 0 if log is None else len(log.entries)
+            replay(endpoints, classes, result, plan)
+            if span is not None and log is not None:
+                span.attrs["messages"] = len(log.entries) - booked
+        self._compiled_ctx = (tuple(endpoints), plan, kept)
+        return fitted
+
+    def _attach_telemetry(self) -> None:
+        if self.telemetry is not None:
+            # attach before any booking: the replay walk (and the variant
+            # lowerings' replays) then emit into the registry through the
+            # same TransportLog/accountant hooks the eager path uses
+            self.telemetry.attach_transport(self.transport)
+
+    def _compiled_plan(self, endpoints: Sequence[AgentEndpoint],
+                       validation):
+        """The :class:`repro.core.compiled.SessionPlan` of an ASCII run on
+        this protocol's config, transport and scheduler; raises for what
+        the compiled backend does not lower."""
+        from repro.core import compiled
+        cfg = self.cfg
         if self.scenario is not None and not self.scenario.trivial:
             raise ValueError(
                 "backend='compiled' does not lower ASCII scenario knobs "
@@ -1535,7 +1602,7 @@ class Protocol:
         if not all(ep.active for ep in endpoints):
             raise ValueError("backend='compiled' assumes all endpoints "
                              "active for the whole run")
-        plan = compiled.plan_for(
+        return compiled.plan_for(
             [ep.learner for ep in endpoints], cfg.num_classes,
             max_rounds=cfg.max_rounds, upstream=cfg.upstream,
             stop_on_negative_alpha=cfg.stop_on_negative_alpha,
@@ -1555,38 +1622,6 @@ class Protocol:
             controller=self.transport.controller,
             serve_controller=self.transport.serve_controller,
             scheduler=sched_plan)
-        live_sink = self._live_sink()
-        live = live_sink is not None
-        if isinstance(sched_plan, compiled.AsyncStalePlan):
-            with self._span("session", backend="compiled",
-                            agents=len(endpoints)):
-                with live_installed(live_sink):
-                    result = self._fence(compiled.async_session(
-                        plan, key, tuple(ep.X for ep in endpoints),
-                        classes, live=live))
-            fitted = compiled.fitted_from_async_result(
-                plan, result, [ep.learner for ep in endpoints])
-            with self._span("replay", backend="compiled"):
-                self._replay_traffic_async(endpoints, classes, result, plan)
-            self._compiled_ctx = (tuple(endpoints), plan, result)
-            return fitted
-        with self._span("session", backend="compiled",
-                        agents=len(endpoints)):
-            # the fence closes the span at computation-done, not at
-            # async-dispatch enqueue — timing only, values untouched
-            with live_installed(live_sink):
-                result = self._fence(compiled.compiled_session(
-                    plan, key, tuple(ep.X for ep in endpoints), classes,
-                    live=live))
-        fitted = compiled.fitted_from_result(
-            plan, result, [ep.learner for ep in endpoints])
-        with self._span("replay", backend="compiled"):
-            self._replay_traffic(endpoints, classes, result, plan)
-        # the serve path indexes per-agent state positionally: store the
-        # agent-major view (identity re-collection for sequential plans)
-        self._compiled_ctx = (tuple(endpoints), plan,
-                              compiled.agent_major_result(result))
-        return fitted
 
     def _replay_traffic(self, endpoints: Sequence[AgentEndpoint],
                         classes: jnp.ndarray, result, plan=None) -> None:

@@ -2,9 +2,10 @@
 
 One :class:`Telemetry` object owns a :class:`MetricsRegistry` (every
 counter the system keeps: wire bits, DP releases, budget skips, admission
-outcomes, cache/batch events) and a :class:`SpanTracer` (session -> round
--> hop on the train path, flush -> flush_wave -> bucket_dispatch on the
-serve path), plus the attach/export plumbing that wires them into a run:
+outcomes, cache/batch events) and a :class:`SpanTracer` (fit -> session ->
+round -> hop on the eager train path, fit -> plan/session/extract/replay on
+the compiled one, flush -> flush_wave -> bucket_dispatch on the serve path),
+plus the attach/export plumbing that wires them into a run:
 
     tele = Telemetry()
     proto = Protocol(..., telemetry=tele)
@@ -38,20 +39,18 @@ class Telemetry:
     """Registry + tracer + attach/export plumbing for one run.
 
     ``profile`` additionally opens ``jax.profiler`` trace annotations per
-    span (pair with ``jax.profiler.trace(dir)`` around the run); ``fence``
-    controls the ``block_until_ready`` fences at dispatch boundaries
-    (timing-only — on by default so span durations measure computation,
-    not async-dispatch enqueue); ``live`` opens the in-flight emission
-    plane (:mod:`repro.telemetry.live`): compiled programs stream
-    per-round taps into this registry *while executing* instead of going
-    dark until the post-run replay.
+    span (pair with ``jax.profiler.trace(dir)`` around the run).  Spans
+    fence at dispatch boundaries (``block_until_ready``, timing only), so
+    their durations measure computation, not async-dispatch enqueue.
+    ``live`` opens the in-flight emission plane
+    (:mod:`repro.telemetry.live`): compiled programs stream per-round taps
+    into this registry *while executing* instead of going dark until the
+    post-run replay.
     """
 
-    def __init__(self, *, profile: bool = False, fence: bool = True,
-                 live: bool = False):
+    def __init__(self, *, profile: bool = False, live: bool = False):
         self.registry = MetricsRegistry()
-        self.tracer = SpanTracer(self.registry, profile=profile,
-                                 fence=fence)
+        self.tracer = SpanTracer(self.registry, profile=profile)
         self.live: LiveSink | None = (LiveSink(self.registry)
                                       if live else None)
         self._stream: StreamingTraceWriter | None = None
@@ -110,9 +109,15 @@ class Telemetry:
                 self.registry.inc("dp_releases_total", count, agent=agent)
             accountant.registry = self.registry
 
-    def sync_gauges(self, transport) -> None:
-        """Copy the budget state that isn't event-shaped (per-link spent
-        bits, the exhausted flag) into gauges — called at export time."""
+    def sync_gauges(self, transport=None) -> None:
+        """Copy the state that isn't event-shaped into gauges — called at
+        export time: the transport's budget state (per-link spent bits,
+        the exhausted flag) and the process's compiled-program trace counts
+        (``program_traces{program=...}``, from
+        :data:`repro.core.compiled.TRACE_COUNTS`)."""
+        from repro.core.compiled import TRACE_COUNTS
+        for family, count in sorted(TRACE_COUNTS.items()):
+            self.registry.set_gauge("program_traces", count, program=family)
         for (src, dst), bits in sorted(
                 getattr(transport, "link_spent", {}).items()):
             self.registry.set_gauge("budget_link_spent_bits", bits,
@@ -127,8 +132,7 @@ class Telemetry:
                         transport=None) -> None:
         """Write the requested artifacts (``--trace`` JSONL event log,
         ``--metrics-out`` JSON snapshot or ``.prom`` text)."""
-        if transport is not None:
-            self.sync_gauges(transport)
+        self.sync_gauges(transport)
         if trace:
             if self._stream is not None and self._stream.path == trace:
                 # the run streamed here all along: seal with the metric

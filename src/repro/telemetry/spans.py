@@ -1,5 +1,6 @@
-"""Span tracing for protocol runs: session -> round -> hop on the train
-path, flush -> flush_wave -> bucket_dispatch on the serve path.
+"""Span tracing for protocol runs: fit -> session -> round -> hop on the
+eager train path, fit -> {plan, session, extract, replay} on the compiled
+one, flush -> flush_wave -> bucket_dispatch on the serve path.
 
 A :class:`Span` is a closed wall-clock interval with a name, a parent, and
 JSON-able attributes; the :class:`SpanTracer` maintains the open-span stack
