@@ -233,10 +233,10 @@ def plan_for(learners: Sequence, num_classes: int, *, max_rounds: int = 20,
 # ==================================================================== lowering
 #: Trace-entry counters keyed by program family (``session``,
 #: ``async_session``, ``serve``, ``serve_batch``, ``fleet``, ``sweep``,
-#: ``sweep_serve``, ``control_sweep``): one increment each time a family's
-#: program is traced, none per call.  A correctly cached program traces
-#: once however often it runs, and a sweep once however many configs it
-#: vmaps over; ``Telemetry.sync_gauges`` exports the totals.
+#: ``sweep_serve``, ``control_sweep``, ``extract``): one increment each time
+#: a family's program is traced, none per call.  A correctly cached program
+#: traces once however often it runs, and a sweep once however many configs
+#: it vmaps over; ``Telemetry.sync_gauges`` exports the totals.
 TRACE_COUNTS: dict = {}
 
 
@@ -965,13 +965,13 @@ def fitted_from_async_result(plan: SessionPlan, result: AsyncSessionResult,
     — byte-compatible with the eager ``_step_stale`` session's
     ``fitted()``.  Agent-major throughout (the barrier has no chain order);
     every executed round records all M alphas/accs, components come from
-    the positive-alpha subset in id order."""
+    the positive-alpha subset in id order, their parameters from one
+    launch of the extraction program (:func:`extract_params`)."""
     from repro.core.engine import Component, FittedASCII
 
-    alphas = np.asarray(result.alphas)
-    accs = np.asarray(result.accs)
-    executed = np.asarray(result.executed)
-    valid = np.asarray(result.valid)
+    params = extract_params(result.params)
+    alphas, accs, executed, valid = jax.device_get(
+        (result.alphas, result.accs, result.executed, result.valid))
     components, history = [], []
     for t in range(plan.max_rounds):
         if not executed[t].any():
@@ -981,10 +981,8 @@ def fitted_from_async_result(plan: SessionPlan, result: AsyncSessionResult,
                "accs": [float(a) for a in accs[t]]}
         for m in range(plan.num_agents):
             if valid[t, m]:
-                params_tm = jax.tree.map(lambda x, _t=t: x[_t],
-                                         result.params[m])
                 components.append(Component(m, t, float(alphas[t, m]),
-                                            params_tm))
+                                            params[m][t]))
         history.append(rec)
     return FittedASCII(components, list(learners), plan.num_classes, history)
 
@@ -1463,6 +1461,32 @@ def control_sweep_run(plan: SessionPlan, keys: jax.Array,
 
 
 # ============================================================= host extraction
+def _slot_slices(params):
+    """Every (slot, round) slice of stacked per-slot params: a tuple over
+    slots, then rounds, of the slot's pytree."""
+    return tuple(
+        tuple(jax.tree.map(lambda x, _t=t: x[_t], p)
+              for t in range(jax.tree.leaves(p)[0].shape[0]))
+        for p in params)
+
+
+@functools.lru_cache(maxsize=64)
+def _extract_program(treedef, avals: tuple):
+    return jax.jit(_counted("extract", _slot_slices))
+
+
+def extract_params(params: tuple) -> tuple:
+    """Split a result's stacked per-slot params (M pytrees with leading
+    round axis T) into ``out[j][t]``, slot ``j``'s params of round ``t``,
+    each leaf its own device array, bit-identical to ``x[t]``.  One
+    program launch for all T x M slots (one eager slice per leaf and slot
+    costs a host dispatch each); cached per (treedef, leaf shapes and
+    dtypes), so a configuration traces it once."""
+    leaves, treedef = jax.tree.flatten(params)
+    avals = tuple((x.shape, x.dtype) for x in leaves)
+    return _extract_program(treedef, avals)(params)
+
+
 def agent_major_result(result: SessionResult) -> SessionResult:
     """Re-collect a slot-major :class:`SessionResult` to agent-major.
 
@@ -1508,15 +1532,18 @@ def fitted_from_result(plan: SessionPlan, result: SessionResult,
     ``Protocol.fit`` returns on the eager path.  Slot-major input: under a
     permuting scheduler the component agent ids come from ``result.order``
     (slot ``j`` holds agent ``order[t, j]``), matching the eager visit
-    order exactly."""
+    order exactly.  Component parameters come from one launch of the
+    extraction program (:func:`extract_params`); the result's scalars reach
+    the host in one batched fetch, whose host copies the ledger replay then
+    reads again for free."""
     from repro.core.engine import Component, FittedASCII
 
-    alphas = np.asarray(result.alphas)
-    accs = np.asarray(result.accs)
-    executed = np.asarray(result.executed)
-    valid = np.asarray(result.valid)
-    order = getattr(result, "order", None)
-    order = None if order is None else np.asarray(order)
+    # launch before the fetch: the launch's host cost (about one buffer per
+    # output leaf) then overlaps a session still running on the device
+    params = extract_params(result.params)
+    alphas, accs, executed, valid, order = jax.device_get(
+        (result.alphas, result.accs, result.executed, result.valid,
+         getattr(result, "order", None)))
     components, history = [], []
     for t in range(plan.max_rounds):
         if not executed[t].any():
@@ -1529,9 +1556,7 @@ def fitted_from_result(plan: SessionPlan, result: SessionResult,
             rec["accs"].append(float(accs[t, j]))
             if valid[t, j]:
                 agent = j if order is None else int(order[t, j])
-                params_tj = jax.tree.map(lambda x, _t=t: x[_t],
-                                         result.params[j])
                 components.append(Component(agent, t, float(alphas[t, j]),
-                                            params_tj))
+                                            params[j][t]))
         history.append(rec)
     return FittedASCII(components, list(learners), plan.num_classes, history)

@@ -1501,7 +1501,10 @@ class Protocol:
         (transport attach, scheduler bind, ``plan_for``), ``session`` (the
         fenced compiled call; ``traced`` counts the programs it traced),
         ``extract`` (the fitted ensemble and the agent-major view; its
-        ``components`` and ``leaves``, the eager parameter slices), and
+        ``components``, the parameter ``leaves`` they hold, and
+        ``dispatches``, the device programs extraction launched: the one
+        extraction program, plus a slice per (round, leaf) and a stack per
+        leaf where a permuting plan needs the agent-major view), and
         ``replay`` (the ledger; ``messages``, the entries it books on a
         metered transport)."""
         from repro.core import compiled
@@ -1545,11 +1548,13 @@ class Protocol:
             if span is not None:
                 leaves = sum(len(jax.tree.leaves(c.params))
                              for c in fitted.components)
+                dispatches = 1
                 if kept is not result:   # one slice per (round, leaf)
-                    leaves += (result.alphas.shape[0]
-                               * len(jax.tree.leaves(result.params)))
+                    stacked = len(jax.tree.leaves(result.params))
+                    leaves += result.alphas.shape[0] * stacked
+                    dispatches += (result.alphas.shape[0] + 1) * stacked
                 span.attrs.update(components=len(fitted.components),
-                                  leaves=leaves)
+                                  leaves=leaves, dispatches=dispatches)
         replay = self._replay_traffic_async if stale else self._replay_traffic
         log = getattr(self.transport, "log", None)
         with self._span("replay", backend="compiled") as span:

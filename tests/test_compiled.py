@@ -12,8 +12,10 @@ import numpy as np
 import pytest
 from program_tolerance import assert_floats_close, assert_history_close
 
-from repro.core.compiled import (SessionPlan, compiled_session, fleet_run,
-                                 plan_for)
+from repro.control.scheduler import BudgetAwarePlan
+from repro.core.compiled import (AsyncStalePlan, SessionPlan, async_session,
+                                 compiled_session, fitted_from_async_result,
+                                 fitted_from_result, fleet_run, plan_for)
 from repro.core.engine import (MeteredTransport, Protocol, RandomScheduler,
                                SessionConfig, endpoints_for)
 from repro.data.partition import train_test_split, vertical_split
@@ -140,6 +142,81 @@ def test_compiled_matches_eager_early_stop(blob):
     assert eager.num_rounds == 1
     assert len(eager.history[0]["alphas"]) == 2   # head + triggering agent
     _assert_identical(eager, comp, Xte[:3])
+
+
+# ---------------------------------------------------------- result extraction
+def _per_leaf_components(plan, result, stale):
+    """The reference the extraction program must equal: one eager slice per
+    parameter leaf of each valid slot, in the order ``FittedASCII`` lists
+    its components."""
+    alphas = np.asarray(result.alphas)
+    executed = np.asarray(result.executed)
+    valid = np.asarray(result.valid)
+    order = getattr(result, "order", None)
+    order = None if order is None else np.asarray(order)
+    out = []
+    for t in range(plan.max_rounds):
+        if not executed[t].any():
+            break
+        for j in range(plan.num_agents):
+            if not stale and not executed[t, j]:
+                break
+            if valid[t, j]:
+                agent = j if order is None else int(order[t, j])
+                out.append((agent, t, float(alphas[t, j]),
+                            jax.tree.map(lambda x, _t=t: x[_t],
+                                         result.params[j])))
+    return out
+
+
+def _extraction_run(blob, case):
+    """(plan, compiled result, learners) of one extraction case."""
+    Xtr, ctr, _, _, k = blob
+    kw = {}
+    if case == "early_stop":
+        ctr = jnp.where(ctr == k - 1, 0, ctr)
+        learners = [LogisticRegression(steps=30), _ConstLearner(k),
+                    LogisticRegression(steps=30)]
+        Xtr = Xtr[:3]
+    elif case == "sequential":
+        learners = [MLP(hidden=(8,), steps=10) for _ in Xtr]
+    else:
+        learners = [LogisticRegression(steps=30) for _ in Xtr]
+        kw["scheduler"] = (AsyncStalePlan() if case == "async_stale"
+                           else BudgetAwarePlan(spend_signal="none"))
+    plan = plan_for(learners, k, max_rounds=3, **kw)
+    run = async_session if case == "async_stale" else compiled_session
+    return plan, run(plan, jax.random.key(5), Xtr, ctr), learners
+
+
+@pytest.mark.parametrize("case", ["sequential", "async_stale", "early_stop",
+                                  "permuted"])
+def test_extraction_matches_per_leaf_slices(blob, case):
+    """One extraction program gives every component the agent, round,
+    alpha and parameters, leaf for leaf and bit for bit, that slicing each
+    leaf on its own gives."""
+    plan, result, learners = _extraction_run(blob, case)
+    stale = case == "async_stale"
+    build = fitted_from_async_result if stale else fitted_from_result
+    fitted = build(plan, result, learners)
+    want = _per_leaf_components(plan, result, stale)
+    assert want
+    assert [(c.agent, c.round, c.alpha) for c in fitted.components] == \
+           [w[:3] for w in want]
+    for c, (*_, params) in zip(fitted.components, want):
+        assert jax.tree.structure(c.params) == jax.tree.structure(params)
+        for got, ref in zip(jax.tree.leaves(c.params),
+                            jax.tree.leaves(params)):
+            assert isinstance(got, jax.Array)
+            assert got.dtype == ref.dtype
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    if case == "sequential":       # later rounds slice other rows
+        assert len({c.round for c in fitted.components}) > 1
+    if case == "early_stop":       # the stop tripped mid-round
+        assert [len(r["alphas"]) for r in fitted.history] == [2]
+    if case == "permuted":         # the scheduler really reordered a round
+        order = np.asarray(result.order)
+        assert any(list(row) != sorted(row) for row in order)
 
 
 # ------------------------------------------------------------------ the fleet

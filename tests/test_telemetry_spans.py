@@ -4,7 +4,8 @@ goes.
 A ``Protocol.fit`` is one ``fit`` span.  On the compiled backend its
 children are ``plan`` (transport attach, scheduler bind, ``plan_for``),
 ``session`` (the fenced compiled call, with ``traced``: the programs traced
-during it), ``extract`` (the fitted ensemble and the agent-major view) and
+during it), ``extract`` (the fitted ensemble and the agent-major view, with
+``dispatches``: the device programs it launched) and
 ``replay`` (the ledger); on the eager backend ``session`` -> ``round`` ->
 ``hop``.  In the compiled round body the model weight and reweight carry an
 ``ascii_update_<j>`` scope and the wire channel an ``ascii_channel_<j>``
@@ -87,6 +88,7 @@ def test_compiled_fit_span_tree(blob):
     assert by["extract"].attrs["components"] == len(fitted.components)
     # a logistic component's parameters are two leaves (w, b)
     assert by["extract"].attrs["leaves"] == 2 * len(fitted.components)
+    assert by["extract"].attrs["dispatches"] == 1
     assert by["replay"].attrs["messages"] == len(transport.log.entries)
 
 
@@ -117,6 +119,20 @@ def test_session_span_counts_traces(blob):
     traced = [s.attrs["traced"] for s in tele.tracer.spans
               if s.name == "session"]
     assert traced[0] >= 1 and traced[1] == 0
+
+
+def test_extract_is_one_program_traced_once(blob):
+    """Every fit of one configuration builds its ensemble with one launch
+    of one extraction program, traced by the first fit only."""
+    compiled._extract_program.cache_clear()
+    compiled.TRACE_COUNTS.clear()
+    tele = Telemetry()
+    fits = [_fit(blob, "compiled", tele, key=k)[0] for k in (1, 2)]
+    assert compiled.TRACE_COUNTS["extract"] == 1
+    spans = [s for s in tele.tracer.spans if s.name == "extract"]
+    assert [s.attrs["dispatches"] for s in spans] == [1, 1]
+    assert [s.attrs["leaves"] for s in spans] == \
+           [2 * len(f.components) for f in fits]
 
 
 def test_session_program_scopes_update_and_channel(blob):
@@ -205,11 +221,13 @@ def _family_runs(blob):
                           lambda _: compiled.control_sweep_run(
                               ctrl, keys, Xtr, ctr,
                               betas=[0.0, 0.5])),
+        "extract": (compiled._extract_program, result,
+                    lambda res: compiled.extract_params(res.params)),
     }
 
 
 FAMILIES = ("session", "async_session", "serve", "serve_batch", "fleet",
-            "sweep", "sweep_serve", "control_sweep")
+            "sweep", "sweep_serve", "control_sweep", "extract")
 
 
 @pytest.mark.parametrize("family", FAMILIES)
