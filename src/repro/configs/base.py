@@ -29,8 +29,18 @@ class ArchConfig:
     window: int | None = None           # sliding-window size (SWA)
     rope_theta: float = 10_000.0
     logit_softcap: float | None = None
+    # YaRN rope scaling (DeepSeek-V2): 0 = plain rope; otherwise the factor,
+    # the published mscale and mscale_all_dim, the original context length
+    # and the ramp's beta_fast/beta_slow rotation counts
+    yarn_factor: float = 0.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
 
-    # MLA (MiniCPM3 / DeepSeek-style multi-head latent attention)
+    # MLA (MiniCPM3 / DeepSeek-style multi-head latent attention);
+    # q_lora_rank 0 projects the query straight from d_model (DeepSeek-V2-Lite)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_rope_head_dim: int = 0
@@ -47,6 +57,14 @@ class ArchConfig:
     moe_every: int = 1                  # MoE block every k-th layer
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    norm_topk_prob: bool = True         # renormalize the top-k gate weights
+    shared_experts: int = 0             # always-on experts, one SwiGLU of
+                                        # width shared_experts * moe_d_ff
+    experts_held: int = 0               # expert-parallel share: this device
+                                        # holds experts [0, experts_held) of
+                                        # num_experts (0 = all of them)
+    first_k_dense: int = 0              # leading layers with a dense FFN of
+                                        # width d_ff before the MoE layers
 
     # SSM (Mamba2 SSD)
     ssm_state: int = 0
@@ -95,6 +113,11 @@ class ArchConfig:
         return self.num_experts > 0
 
     @property
+    def held_experts(self) -> int:
+        """Experts whose weights this device holds."""
+        return self.experts_held or self.num_experts
+
+    @property
     def d_inner(self) -> int:           # SSM inner width
         return self.ssm_expand * self.d_model
 
@@ -125,7 +148,8 @@ class ArchConfig:
         if self.is_moe:
             kw.update(num_experts=min(self.num_experts, 4),
                       top_k=min(self.top_k, 2),
-                      moe_d_ff=max(32, min(self.moe_d_ff, 128)))
+                      moe_d_ff=max(32, min(self.moe_d_ff, 128)),
+                      experts_held=min(self.experts_held, 4))
         if self.attention == "mla":
             kw.update(q_lora_rank=min(self.q_lora_rank, 64) or 0,
                       kv_lora_rank=min(self.kv_lora_rank, 32),

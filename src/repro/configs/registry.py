@@ -13,6 +13,7 @@ from repro.configs.jamba_v0_1_52b import CONFIG as jamba_v0_1_52b
 from repro.configs.internvl2_2b import CONFIG as internvl2_2b
 from repro.configs.qwen3_0_6b import CONFIG as qwen3_0_6b
 from repro.configs.minicpm3_4b import CONFIG as minicpm3_4b
+from repro.configs.deepseek_v2_lite import CONFIG as deepseek_v2_lite
 
 ARCHS: dict[str, ArchConfig] = {c.name: c for c in [
     granite_moe_1b_a400m,
@@ -25,6 +26,7 @@ ARCHS: dict[str, ArchConfig] = {c.name: c for c in [
     internvl2_2b,
     qwen3_0_6b,
     minicpm3_4b,
+    deepseek_v2_lite,
 ]}
 
 

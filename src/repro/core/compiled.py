@@ -178,6 +178,11 @@ class SessionResult(NamedTuple):
     rows under sequential plans, the in-scan budget-aware permutation
     otherwise (``agent_major_result`` re-collects a permuted result into
     agent-major order for the serve path).
+
+    ``counts`` is a length-M tuple of per-slot dicts of int32 [T] work
+    counts, what each hop's ``LearnerCore.fit_counted`` and
+    ``predict_counted`` report (``{}`` for cores that count nothing);
+    :func:`work_counts` sums them over the executed slots.
     """
     alphas: jnp.ndarray
     accs: jnp.ndarray
@@ -190,6 +195,26 @@ class SessionResult(NamedTuple):
     codec_idx: jnp.ndarray
     exhausted: jnp.ndarray
     order: jnp.ndarray = None
+    counts: tuple = None
+
+
+def work_counts(result: SessionResult) -> dict:
+    """Each work count of a session summed over the (round, slot) hops it
+    executed, as host ints (``{}`` where no core counts), with
+    ``expert_tokens``, the fits' and predicts' routed pairs together."""
+    if not result.counts or not any(result.counts):
+        return {}
+    executed, counts = jax.device_get((result.executed, result.counts))
+    out: dict = {}
+    for j, per_slot in enumerate(counts):
+        for name, values in per_slot.items():
+            out[name] = out.get(name, 0) + int(
+                np.sum(np.asarray(values, np.int64)[executed[:, j]]))
+    routed = [v for name, v in out.items()
+              if name.startswith("expert_tokens_")]
+    if routed:
+        out["expert_tokens"] = sum(routed)
+    return out
 
 
 def plan_for(learners: Sequence, num_classes: int, *, max_rounds: int = 20,
@@ -233,10 +258,13 @@ def plan_for(learners: Sequence, num_classes: int, *, max_rounds: int = 20,
 # ==================================================================== lowering
 #: Trace-entry counters keyed by program family (``session``,
 #: ``async_session``, ``serve``, ``serve_batch``, ``fleet``, ``sweep``,
-#: ``sweep_serve``, ``control_sweep``, ``extract``): one increment each time
-#: a family's program is traced, none per call.  A correctly cached program
-#: traces once however often it runs, and a sweep once however many configs
-#: it vmaps over; ``Telemetry.sync_gauges`` exports the totals.
+#: ``sweep_serve``, ``control_sweep``, ``extract``; and, for a learner core
+#: with a ``trace_family`` such as the neural backbone's, ``<family>_fit``
+#: and ``<family>_predict`` for each session program its hops are traced
+#: into): one increment each time a family's program is traced, none per
+#: call.  A correctly cached program traces once however
+#: often it runs, and a sweep once however many configs it vmaps over;
+#: ``Telemetry.sync_gauges`` exports the totals.
 TRACE_COUNTS: dict = {}
 
 
@@ -455,6 +483,11 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
             # named_scope tags the HLO so profiler traces group ops by hop
             # (metadata only — the lowered computation is unchanged).
             for j, core in enumerate(cores):
+                fit, predict = core.fit_counted, core.predict_counted
+                if core.trace_family is not None:
+                    fit = _counted(f"{core.trace_family}_fit", fit)
+                    predict = _counted(f"{core.trace_family}_predict",
+                                       predict)
                 if scheduler is None:
                     src = j                       # slot j == agent j
                     X_j, shape_j = Xs[j], feature_shapes[j]
@@ -464,10 +497,13 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
                     X_j, shape_j = Xstack[src], feature_shapes[0]
                 with jax.named_scope(f"ascii_hop_{j}"):
                     key, sub = jax.random.split(key)
-                    params = core.fit(core.init(sub, shape_j), sub,
-                                      X_j, onehot, w)
-                    r = (core.predict(params, X_j) == classes
-                         ).astype(jnp.float32)
+                    params, fit_counts = fit(core.init(sub, shape_j), sub,
+                                             X_j, onehot, w)
+                    pred, pred_counts = predict(params, X_j)
+                    r = (pred == classes).astype(jnp.float32)
+                    counts = {name: fit_counts.get(name, 0)
+                              + pred_counts.get(name, 0)
+                              for name in {**fit_counts, **pred_counts}}
                 # eq. (13) weight, the stop rule, eqs. (10)/(12): a scope
                 # of their own beside the hop's, as is the channel below
                 with jax.named_scope(f"ascii_update_{j}"):
@@ -607,7 +643,7 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
                         jnp.asarray(0, jnp.int32))
                 stopped = stopped | trigger
                 outs.append((params, a, rbar, executed, valid, w, sent,
-                             rung, jnp.asarray(src, jnp.int32)))
+                             rung, jnp.asarray(src, jnp.int32), counts))
             if budget is not None \
                     and (control_arg or budget.session_bits is not None):
                 # the eager engine notices exhaustion at the *next* round's
@@ -663,7 +699,8 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
             sent=jnp.stack([y[6] for y in ys], axis=1),
             codec_idx=jnp.stack([y[7] for y in ys], axis=1),
             exhausted=fin.get("exhausted", jnp.zeros((), bool)),
-            order=jnp.stack([y[8] for y in ys], axis=1))
+            order=jnp.stack([y[8] for y in ys], axis=1),
+            counts=tuple(y[9] for y in ys))
 
     if control_arg:
         return (lambda key, Xs, classes, cuts, beta, session_cap, link_cap:
@@ -969,9 +1006,9 @@ def fitted_from_async_result(plan: SessionPlan, result: AsyncSessionResult,
     launch of the extraction program (:func:`extract_params`)."""
     from repro.core.engine import Component, FittedASCII
 
-    params = extract_params(result.params)
     alphas, accs, executed, valid = jax.device_get(
         (result.alphas, result.accs, result.executed, result.valid))
+    params = extract_params(result.params)
     components, history = [], []
     for t in range(plan.max_rounds):
         if not executed[t].any():
@@ -1538,12 +1575,12 @@ def fitted_from_result(plan: SessionPlan, result: SessionResult,
     reads again for free."""
     from repro.core.engine import Component, FittedASCII
 
-    # launch before the fetch: the launch's host cost (about one buffer per
-    # output leaf) then overlaps a session still running on the device
-    params = extract_params(result.params)
+    # the fetch waits for the session to finish, so the copies the launch
+    # allocates never sit beside the session's own working memory
     alphas, accs, executed, valid, order = jax.device_get(
         (result.alphas, result.accs, result.executed, result.valid,
          getattr(result, "order", None)))
+    params = extract_params(result.params)
     components, history = [], []
     for t in range(plan.max_rounds):
         if not executed[t].any():

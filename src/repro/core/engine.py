@@ -1506,7 +1506,11 @@ class Protocol:
         extraction program, plus a slice per (round, leaf) and a stack per
         leaf where a permuting plan needs the agent-major view), and
         ``replay`` (the ledger; ``messages``, the entries it books on a
-        metered transport)."""
+        metered transport).  Where the learners count their work (the
+        neural backbone), the ``session`` span also carries the executed
+        hops' sums: ``tokens_fit``, ``tokens_predict`` and
+        ``expert_tokens`` (split as ``expert_tokens_fit`` and
+        ``expert_tokens_predict``), the tokens routed to held experts."""
         from repro.core import compiled
         if not isinstance(self.variant, ASCIIVariant):
             self._attach_telemetry()
@@ -1533,6 +1537,8 @@ class Protocol:
             if span is not None:
                 span.attrs["traced"] = (sum(compiled.TRACE_COUNTS.values())
                                         - traced)
+                if not stale:
+                    span.attrs.update(compiled.work_counts(result))
         learners = [ep.learner for ep in endpoints]
         with self._span("extract") as span:
             if stale:
@@ -1564,6 +1570,13 @@ class Protocol:
                 span.attrs["messages"] = len(log.entries) - booked
         self._compiled_ctx = (tuple(endpoints), plan, kept)
         return fitted
+
+    @property
+    def compiled_result(self):
+        """The last compiled ASCII fit's result, agent-major: every (round,
+        agent) hop's parameters and scores, the hops that stopped the run
+        included (None before one)."""
+        return None if self._compiled_ctx is None else self._compiled_ctx[2]
 
     def _attach_telemetry(self) -> None:
         if self.telemetry is not None:

@@ -91,6 +91,34 @@ def mimic_surrogate(key, n: int = 15000) -> Dataset:
                               splits=(3, 13), informative_frac=0.6)
 
 
+def mimic_notes(key, n: int = 15000, *, length: int = 512,
+                vocab: int = 12800, noise: float = 1.0, cue_rate: float = 0.05,
+                blank: float = 0.25, cue_words: int = 64):
+    """MIMIC-III with clinical notes beside the chart (paper Sec. VI: one
+    agent per data source over the same subjects): the 16 chart features
+    of :func:`mimic_surrogate` (pixel-free tabular, ``noise`` the class
+    spread's noise) and a note of ``length`` token ids in ``[0, vocab)``
+    per subject.
+
+    A note is background ids drawn uniformly, where each position with
+    probability ``cue_rate`` is instead one of its class's ``cue_words``
+    cue ids (drawn once per class); a ``blank`` share of notes never
+    mention the finding and carry no cue, so the notes alone cannot fit
+    every subject.  Returns (notes [n, length] int32, chart [n, 16] f32,
+    classes [n] int32)."""
+    k_chart, k_cue, k_bg, k_which, k_on, k_said = jax.random.split(key, 6)
+    chart = _tabular_surrogate(k_chart, name="mimic", n=n, p=16,
+                               num_classes=2, splits=(3, 13),
+                               informative_frac=0.6, noise=noise)
+    cues = jax.random.randint(k_cue, (chart.num_classes, cue_words), 0, vocab)
+    background = jax.random.randint(k_bg, (n, length), 0, vocab)
+    which = jax.random.randint(k_which, (n, length), 0, cue_words)
+    said = jax.random.bernoulli(k_said, 1.0 - blank, (n,))
+    on = jax.random.bernoulli(k_on, cue_rate, (n, length)) & said[:, None]
+    notes = jnp.where(on, cues[chart.classes[:, None], which], background)
+    return notes.astype(jnp.int32), chart.X, chart.classes
+
+
 def qsar_surrogate(key, n: int = 1055) -> Dataset:
     """QSAR biodegradation surrogate: p=41, K=2, split 20/21."""
     return _tabular_surrogate(key, name="qsar", n=n, p=41, num_classes=2,

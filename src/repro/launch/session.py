@@ -11,6 +11,9 @@ is for LM training.
       --stop-after 2                       # save mid-run ...
   PYTHONPATH=src python -m repro.launch.session --ckpt-dir /tmp/sess \
       --resume                             # ... and pick the run back up
+  PYTHONPATH=src python -m repro.launch.session --dataset notes \
+      --learner backbone --arch deepseek-v2-lite --reduced --steps 8 \
+      --backend compiled                   # a text agent beside a chart MLP
 """
 from __future__ import annotations
 
@@ -35,6 +38,7 @@ from repro.data import synthetic
 from repro.launch.compile_cache import enable_compile_cache
 from repro.learners.logistic import LogisticRegression
 from repro.learners.mlp import MLP
+from repro.learners.neural import NeuralBackbone
 from repro.learners.tree import DecisionTree
 from repro.scenarios import PARTITIONS, PRESETS, PROTOCOLS, Scenario, \
     make_variant
@@ -55,11 +59,36 @@ TRANSPORTS = {
 
 LEARNERS = {
     # tree is eager-only; logistic/mlp carry a LearnerCore and can ride
-    # --backend compiled
+    # --backend compiled; backbone is a --arch sequence classifier reading
+    # the notes of --dataset notes, beside an mlp reading the chart
     "tree": lambda args: DecisionTree(depth=args.depth, num_thresholds=8),
     "logistic": lambda args: LogisticRegression(steps=args.steps),
     "mlp": lambda args: MLP(hidden=(32, 16), steps=args.steps),
+    "backbone": lambda args: NeuralBackbone(cfg=_arch(args), steps=args.steps,
+                                            batch_size=16),
 }
+
+
+def _arch(args):
+    from repro.configs.registry import get_arch
+    cfg = get_arch(args.arch)
+    return (cfg.reduced() if args.reduced else cfg).with_overrides(
+        dtype="float32")
+
+
+NOTE_LENGTH = 64           # tokens a note of --dataset notes holds
+
+
+def _dataset(args, key):
+    """(agent blocks, classes, number of classes): the dataset's vertical
+    split, or for ``notes`` each subject's note (token ids of the
+    backbone's vocabulary) and chart."""
+    if args.dataset == "notes":
+        notes, chart, classes = synthetic.mimic_notes(
+            key, args.n, length=NOTE_LENGTH, vocab=_arch(args).vocab_size)
+        return [notes, chart], classes, 2
+    ds = DATASETS[args.dataset](key, args.n)
+    return vertical_split(ds.X, ds.splits), ds.classes, ds.num_classes
 
 
 def _print_comm(transport, show_ema=True):
@@ -122,7 +151,8 @@ def _finish_telemetry(args, telemetry, transport, dash=None):
 def main():
     enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--dataset", default="blob3", choices=sorted(DATASETS))
+    ap.add_argument("--dataset", default="blob3",
+                    choices=sorted(DATASETS) + ["notes"])
     ap.add_argument("--n", type=int, default=600)
     ap.add_argument("--variant", default="ascii",
                     choices=["ascii", "simple", "random", "async"])
@@ -167,7 +197,12 @@ def main():
     ap.add_argument("--depth", type=int, default=3,
                     help="tree depth (tree learner only)")
     ap.add_argument("--steps", type=int, default=150,
-                    help="optimizer steps (logistic/mlp learners)")
+                    help="optimizer steps (logistic/mlp/backbone learners)")
+    ap.add_argument("--arch", default="",
+                    help="backbone learner's architecture (configs/"
+                         "registry.py), e.g. deepseek-v2-lite")
+    ap.add_argument("--reduced", action="store_true",
+                    help="the --arch smoke-test size (CPU)")
     ap.add_argument("--backend", default="eager",
                     choices=["eager", "compiled"],
                     help="compiled lowers the whole run into one lax.scan "
@@ -264,12 +299,16 @@ def main():
                          "only")
     args = ap.parse_args()
 
+    if (args.learner == "backbone") != (args.dataset == "notes"):
+        ap.error("--learner backbone reads the notes of --dataset notes, "
+                 "and only it does")
+    if args.learner == "backbone" and not args.arch:
+        ap.error("--learner backbone needs --arch (e.g. deepseek-v2-lite)")
     key = jax.random.key(args.seed)
-    ds = DATASETS[args.dataset](key, args.n)
-    tr, te = train_test_split(args.seed, ds.X.shape[0])
-    Xs = vertical_split(ds.X, ds.splits)
+    Xs, classes, num_classes = _dataset(args, key)
+    tr, te = train_test_split(args.seed, classes.shape[0])
     Xtr, Xte = [x[tr] for x in Xs], [x[te] for x in Xs]
-    ctr, cte = ds.classes[tr], ds.classes[te]
+    ctr, cte = classes[tr], classes[te]
 
     if args.backend == "compiled":
         if args.resume or args.stop_after or args.ckpt_dir:
@@ -410,15 +449,17 @@ def main():
         dash = Dashboard(telemetry.registry,
                          title=f"session:{args.dataset}"
                          ).attach(telemetry.live)
-    engine = Protocol(SessionConfig(num_classes=ds.num_classes,
+    engine = Protocol(SessionConfig(num_classes=num_classes,
                                     max_rounds=args.rounds,
                                     upstream=upstream),
                       scheduler=scheduler, transport=transport,
                       backend=args.backend, variant=variant_obj,
                       scenario=None if scenario.trivial else scenario,
                       telemetry=telemetry)
-    endpoints = endpoints_for(
-        [LEARNERS[args.learner](args) for _ in Xs], Xtr)
+    learners = [LEARNERS[args.learner](args) for _ in Xs]
+    if args.learner == "backbone":
+        learners[1] = LEARNERS["mlp"](args)     # the chart's agent
+    endpoints = endpoints_for(learners, Xtr)
     if args.profile_dir:
         jax.profiler.start_trace(args.profile_dir)
 

@@ -64,6 +64,11 @@ class LearnerCore(abc.ABC):
     a drop-in for the eager one (tests/test_compiled.py).
     """
 
+    #: Where set, a compiled session counts the traces of this core's hops
+    #: in ``repro.core.compiled.TRACE_COUNTS`` as ``<trace_family>_fit``
+    #: and ``<trace_family>_predict``.
+    trace_family: str | None = None
+
     @abc.abstractmethod
     def init(self, key, shapes: tuple[int, ...]) -> PyTree:
         """Fresh fixed-shape params for feature shape ``shapes``."""
@@ -79,6 +84,17 @@ class LearnerCore(abc.ABC):
 
     def predict(self, params: PyTree, X: jnp.ndarray) -> jnp.ndarray:
         return jnp.argmax(self.logits(params, X), axis=-1)
+
+    def fit_counted(self, params: PyTree, key, X: jnp.ndarray,
+                    onehot: jnp.ndarray, w: jnp.ndarray):
+        """``fit`` and the work it did, a dict of int32 scalars (tokens
+        processed, tokens routed to held experts; ``{}`` where the core
+        counts nothing).  The compiled session sums them per hop."""
+        return self.fit(params, key, X, onehot, w), {}
+
+    def predict_counted(self, params: PyTree, X: jnp.ndarray):
+        """``predict`` and the work it did, as in :meth:`fit_counted`."""
+        return self.predict(params, X), {}
 
 
 class Learner(abc.ABC):

@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models.layers import apply_rope, he_init, rmsnorm, rmsnorm_init
+from repro.models.layers import (apply_rope, he_init, rmsnorm, rmsnorm_init,
+                                 rope_freqs_for, yarn_mscale)
 
 
 class KVCache(NamedTuple):
@@ -199,10 +200,15 @@ def mla_init(key, cfg: ArchConfig, dtype) -> dict:
     r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank
     d_nope, d_rope, d_v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     ks = jax.random.split(key, 6)
+    if r_q:
+        q = {"wq_a": he_init(ks[0], (d, r_q), dtype),
+             "q_a_norm": rmsnorm_init(r_q, dtype),
+             "wq_b": he_init(ks[1], (r_q, h * (d_nope + d_rope)), dtype,
+                             fan_in=r_q)}
+    else:                   # no query latent: one d -> H*(nope+rope) map
+        q = {"wq": he_init(ks[0], (d, h * (d_nope + d_rope)), dtype)}
     return {
-        "wq_a": he_init(ks[0], (d, r_q), dtype),
-        "q_a_norm": rmsnorm_init(r_q, dtype),
-        "wq_b": he_init(ks[1], (r_q, h * (d_nope + d_rope)), dtype, fan_in=r_q),
+        **q,
         "wkv_a": he_init(ks[2], (d, r_kv + d_rope), dtype),
         "kv_a_norm": rmsnorm_init(r_kv, dtype),
         "wk_b": he_init(ks[3], (r_kv, h * d_nope), dtype, fan_in=r_kv),
@@ -215,21 +221,47 @@ def _mla_q(params, x, cfg: ArchConfig, positions):
     b, s, _ = x.shape
     h = cfg.num_heads
     d_nope, d_rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    q = rmsnorm(params["q_a_norm"], jnp.einsum("bsd,dr->bsr", x, params["wq_a"]),
-                cfg.norm_eps)
-    q = jnp.einsum("bsr,re->bse", q, params["wq_b"]).reshape(b, s, h,
-                                                             d_nope + d_rope)
+    if "wq" in params:
+        q = jnp.einsum("bsd,de->bse", x, params["wq"])
+    else:
+        q = rmsnorm(params["q_a_norm"],
+                    jnp.einsum("bsd,dr->bsr", x, params["wq_a"]), cfg.norm_eps)
+        q = jnp.einsum("bsr,re->bse", q, params["wq_b"])
+    q = q.reshape(b, s, h, d_nope + d_rope)
     q_nope, q_rope = q[..., :d_nope], q[..., d_nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     return q_nope, q_rope
+
+
+def _mla_rope(x, positions, cfg: ArchConfig):
+    """Rope on the decoupled rope slice, with YaRN's frequencies and its
+    cos/sin factor ``mscale(f, mscale) / mscale(f, mscale_all_dim)`` where
+    the config scales (1 for DeepSeek-V2-Lite's equal pair)."""
+    out = apply_rope(x, positions, cfg.rope_theta,
+                     rope_freqs_for(cfg, x.shape[-1]))
+    if cfg.yarn_factor:
+        f = cfg.yarn_factor
+        m = (yarn_mscale(f, cfg.yarn_mscale)
+             / yarn_mscale(f, cfg.yarn_mscale_all_dim))
+        if m != 1.0:
+            out = (out.astype(jnp.float32) * m).astype(x.dtype)
+    return out
+
+
+def mla_temperature(cfg: ArchConfig) -> float:
+    """YaRN's ``mscale(f, mscale_all_dim)^2`` on the softmax scale
+    ``(nope + rope)^-1/2`` where the config scales rope, else 1."""
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return 1.0
 
 
 def _mla_latents(params, x, cfg: ArchConfig, positions):
     r_kv, d_rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
     c_kv = rmsnorm(params["kv_a_norm"], kv[..., :r_kv], cfg.norm_eps)
-    k_rope = apply_rope(kv[..., r_kv:][..., None, :], positions,
-                        cfg.rope_theta)[..., 0, :]           # shared head
+    k_rope = _mla_rope(kv[..., r_kv:][..., None, :], positions,
+                       cfg)[..., 0, :]                       # shared head
     return c_kv, k_rope
 
 
@@ -243,6 +275,8 @@ def mla_forward(params, x, cfg: ArchConfig, positions) -> tuple[jnp.ndarray, KVC
         b, s, h, d_nope)
     v = jnp.einsum("btr,re->bte", c_kv, params["wv_b"]).reshape(b, s, h, d_v)
     scale = 1.0 / jnp.sqrt(d_nope + cfg.qk_rope_head_dim)
+    if mla_temperature(cfg) != 1.0:
+        scale = scale * mla_temperature(cfg)
 
     def block(qn, qr, q_offset, c):
         scores = (jnp.einsum("bshd,bthd->bhst", qn, k_nope)
@@ -293,6 +327,8 @@ def mla_decode(params, x, cache: KVCache, pos, cfg: ArchConfig,
     scores = (jnp.einsum("bhr,btr->bht", q_abs, c_kv)
               + jnp.einsum("bshd,btd->bht", q_rope, k_rope)).astype(jnp.float32)
     scores = scores / jnp.sqrt(d_nope + cfg.qk_rope_head_dim)
+    if mla_temperature(cfg) != 1.0:
+        scores = scores * mla_temperature(cfg)
     idx = jnp.arange(s_cache)
     if cache_mode == "ring":
         age = (slot - idx) % s_cache

@@ -7,6 +7,8 @@ table when adding parameters.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -35,11 +37,51 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(factor) + 1``
+    (1 at factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max_position: int, beta_fast: float,
+                     beta_slow: float) -> jnp.ndarray:
+    """YaRN inverse frequencies (DeepSeek-V2): the plain ``theta^(-2i/D)``
+    where a pair turns more than ``beta_fast`` times over the original
+    context, the same divided by ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear ramp between."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_frequencies(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                       # 1 = the unscaled frequency
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def rope_freqs_for(cfg, head_dim: int) -> jnp.ndarray:
+    """The inverse frequencies ``cfg`` rotates a ``head_dim`` slice by."""
+    if cfg.yarn_factor:
+        return yarn_frequencies(head_dim, cfg.rope_theta, cfg.yarn_factor,
+                                cfg.yarn_original_max_position,
+                                cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    return rope_frequencies(head_dim, cfg.rope_theta)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """x: [..., S, H, D]; positions: broadcastable to [..., S]."""
+               theta: float, freqs: jnp.ndarray | None = None) -> jnp.ndarray:
+    """x: [..., S, H, D]; positions: broadcastable to [..., S]; ``freqs``
+    overrides the plain ``theta`` frequencies (YaRN)."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)                       # [D/2]
+    if freqs is None:
+        freqs = rope_frequencies(d, theta)                   # [D/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, D/2]
     cos = jnp.cos(angles)[..., None, :]                      # [..., S, 1, D/2]
     sin = jnp.sin(angles)[..., None, :]

@@ -8,7 +8,13 @@ Three entry points per model, all pure:
   * ``init_params(key, cfg)`` / ``init_cache(cfg, batch, s_cache)``
 
 Hybrid (Jamba) stacks scan over *pattern units* (8 heterogeneous sub-layers
-unrolled inside, 4 scanned repeats).
+unrolled inside, 4 scanned repeats).  Leading dense layers
+(``cfg.first_k_dense``, DeepSeek-V2) are a second stack, ``params["lead"]``,
+scanned before the units; their FFN is the dense SwiGLU of width ``d_ff``.
+
+The mixer, router, routed experts, shared experts and dense FFN run under
+the ``backbone_attn``/``backbone_router``/``backbone_experts``/
+``backbone_shared``/``backbone_ffn`` name scopes (metadata only).
 """
 from __future__ import annotations
 
@@ -44,11 +50,14 @@ def _block_kinds(cfg: ArchConfig) -> tuple[str, ...]:
 
 
 def _num_units(cfg: ArchConfig) -> int:
-    return cfg.num_layers // len(_block_kinds(cfg))
+    return (cfg.num_layers - cfg.first_k_dense) // len(_block_kinds(cfg))
 
 
-def _ffn_kind(cfg: ArchConfig, sub_idx: int) -> str:
-    """What follows the mixer in this sub-layer: moe | mlp | none."""
+def _ffn_kind(cfg: ArchConfig, sub_idx: int, lead: bool = False) -> str:
+    """What follows the mixer in this sub-layer: moe | mlp | none (a
+    leading dense layer always has the dense mlp)."""
+    if lead:
+        return "mlp"
     if cfg.arch_type == "ssm":
         return "none"                       # pure mamba2: no FFN
     if cfg.is_moe:
@@ -58,7 +67,8 @@ def _ffn_kind(cfg: ArchConfig, sub_idx: int) -> str:
     return "mlp"
 
 
-def _init_sub_block(key, cfg: ArchConfig, kind: str, sub_idx: int, dtype):
+def _init_sub_block(key, cfg: ArchConfig, kind: str, sub_idx: int, dtype,
+                    lead: bool = False):
     ks = jax.random.split(key, 4)
     p: dict = {"ln1": rmsnorm_init(cfg.d_model, dtype)}
     if kind == "attn":
@@ -68,7 +78,7 @@ def _init_sub_block(key, cfg: ArchConfig, kind: str, sub_idx: int, dtype):
             p["attn"] = attn.gqa_init(ks[0], cfg, dtype)
     else:
         p["ssm"] = ssm_lib.ssm_init(ks[0], cfg, dtype)
-    ffn = _ffn_kind(cfg, sub_idx)
+    ffn = _ffn_kind(cfg, sub_idx, lead)
     if ffn != "none":
         p["ln2"] = rmsnorm_init(cfg.d_model, dtype)
         if ffn == "moe":
@@ -98,31 +108,36 @@ def _seq_shard(x, cfg: ArchConfig):
 
 
 def _sub_block_forward(p, x, cfg: ArchConfig, kind: str, sub_idx: int,
-                       positions):
-    """Full-seq sub-layer. Returns (x, cache_leaf, aux)."""
+                       positions, lead: bool = False):
+    """Full-seq sub-layer. Returns (x, cache_leaf, aux, expert_tokens)."""
     aux = jnp.zeros((), jnp.float32)
+    count = jnp.zeros((), jnp.int32)
     x = _seq_shard(x, cfg)
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
     if kind == "attn":
-        if cfg.attention == "mla":
-            out, cache = attn.mla_forward(p["attn"], h, cfg, positions)
-        else:
-            out, cache = attn.gqa_forward(p["attn"], h, cfg, positions)
+        with jax.named_scope("backbone_attn"):
+            if cfg.attention == "mla":
+                out, cache = attn.mla_forward(p["attn"], h, cfg, positions)
+            else:
+                out, cache = attn.gqa_forward(p["attn"], h, cfg, positions)
     else:
         out, cache = ssm_lib.ssm_forward(p["ssm"], h, cfg)
     x = x + out
     x = _seq_shard(x, cfg)
-    ffn = _ffn_kind(cfg, sub_idx)
+    ffn = _ffn_kind(cfg, sub_idx, lead)
     if ffn == "moe":
-        y, aux = moe_lib.moe_apply(p["moe"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
+        y, aux, count = moe_lib.moe_layer(
+            p["moe"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
         x = x + y
     elif ffn == "mlp":
-        x = x + mlp_apply(p["mlp"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg.act)
-    return x, cache, aux
+        with jax.named_scope("backbone_ffn"):
+            x = x + mlp_apply(p["mlp"], rmsnorm(p["ln2"], x, cfg.norm_eps),
+                              cfg.act)
+    return x, cache, aux, count
 
 
 def _sub_block_decode(p, x, cache_leaf, pos, cfg: ArchConfig, kind: str,
-                      sub_idx: int, cache_mode: str):
+                      sub_idx: int, cache_mode: str, lead: bool = False):
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
     if kind == "attn":
         if cfg.attention == "mla":
@@ -134,7 +149,7 @@ def _sub_block_decode(p, x, cache_leaf, pos, cfg: ArchConfig, kind: str,
     else:
         out, cache = ssm_lib.ssm_decode(p["ssm"], h, cache_leaf, cfg)
     x = x + out
-    ffn = _ffn_kind(cfg, sub_idx)
+    ffn = _ffn_kind(cfg, sub_idx, lead)
     if ffn == "moe":
         y, _ = moe_lib.moe_apply(p["moe"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
         x = x + y
@@ -144,38 +159,50 @@ def _sub_block_decode(p, x, cache_leaf, pos, cfg: ArchConfig, kind: str,
 
 
 # ------------------------------------------------------------- unit defs
-def _init_unit(key, cfg: ArchConfig, dtype):
-    kinds = _block_kinds(cfg)
+def _unit_kinds(cfg: ArchConfig, lead: bool) -> tuple[str, ...]:
+    """Sub-layer kinds of a scanned unit (a leading dense layer is one
+    attention sub-layer)."""
+    return ("attn",) if lead else _block_kinds(cfg)
+
+
+def _init_unit(key, cfg: ArchConfig, dtype, lead: bool = False):
+    kinds = _unit_kinds(cfg, lead)
     ks = jax.random.split(key, len(kinds))
-    return {f"sub{i}": _init_sub_block(ks[i], cfg, kinds[i], i, dtype)
+    return {f"sub{i}": _init_sub_block(ks[i], cfg, kinds[i], i, dtype, lead)
             for i in range(len(kinds))}
 
 
-def _unit_forward(unit_params, x, cfg: ArchConfig, positions):
-    kinds = _block_kinds(cfg)
+def _unit_forward(unit_params, x, cfg: ArchConfig, positions,
+                  lead: bool = False):
+    """One scanned unit: (x, caches, aux, expert_tokens)."""
+    kinds = _unit_kinds(cfg, lead)
     caches, aux_total = {}, jnp.zeros((), jnp.float32)
+    count_total = jnp.zeros((), jnp.int32)
     for i, kind in enumerate(kinds):
-        x, cache, aux = _sub_block_forward(unit_params[f"sub{i}"], x, cfg,
-                                           kind, i, positions)
+        x, cache, aux, count = _sub_block_forward(
+            unit_params[f"sub{i}"], x, cfg, kind, i, positions, lead)
         caches[f"sub{i}"] = cache
         aux_total = aux_total + aux
-    return x, caches, aux_total
+        count_total = count_total + count
+    return x, caches, aux_total, count_total
 
 
 def _unit_decode(unit_params, x, unit_cache, pos, cfg: ArchConfig,
-                 cache_mode: str):
-    kinds = _block_kinds(cfg)
+                 cache_mode: str, lead: bool = False):
+    kinds = _unit_kinds(cfg, lead)
     new_caches = {}
     for i, kind in enumerate(kinds):
         x, cache = _sub_block_decode(unit_params[f"sub{i}"], x,
                                      unit_cache[f"sub{i}"], pos, cfg, kind, i,
-                                     cache_mode)
+                                     cache_mode, lead)
         new_caches[f"sub{i}"] = cache
     return x, new_caches
 
 
 # --------------------------------------------------------------- model
-def init_params(key, cfg: ArchConfig) -> PyTree:
+def init_params(key, cfg: ArchConfig, with_head: bool = True) -> PyTree:
+    """Embedding, leading dense layers, scanned units, final norm and (with
+    ``with_head``, untied) the output head."""
     dtype = _dtype(cfg)
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     units = _num_units(cfg)
@@ -186,7 +213,12 @@ def init_params(key, cfg: ArchConfig) -> PyTree:
         "layers": layers,
         "final_norm": rmsnorm_init(cfg.d_model, dtype),
     }
-    if not cfg.tie_embeddings:
+    if cfg.first_k_dense:
+        lead_keys = jax.random.split(jax.random.fold_in(k_layers, 1),
+                                     cfg.first_k_dense)
+        params["lead"] = jax.vmap(
+            lambda k: _init_unit(k, cfg, dtype, lead=True))(lead_keys)
+    if with_head and not cfg.tie_embeddings:
         params["lm_head"] = lm_head_init(k_head, cfg.d_model, cfg.vocab_size,
                                          dtype)
     return params
@@ -208,6 +240,41 @@ def embed_inputs(params, batch: dict, cfg: ArchConfig) -> jnp.ndarray:
     return x
 
 
+def layer_stack(params, x, cfg: ArchConfig, positions):
+    """Run the leading dense layers, then the scanned units, over the
+    embedded sequence ``x``.  Returns (x, caches, aux, expert_tokens);
+    ``caches`` holds the leading layers' under ``"lead"``."""
+    def stack(x, layers, lead):
+        def body(carry, unit_params):
+            x, aux, count = carry
+            x, caches, aux_u, count_u = _unit_forward(unit_params, x, cfg,
+                                                      positions, lead)
+            return (x, aux + aux_u, count + count_u), caches
+
+        body_fn = jax.checkpoint(body) if cfg.remat == "block" else body
+        carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
+        if cfg.scan_layers:
+            return jax.lax.scan(body_fn, carry, layers)
+        # Unrolled path: identical math/params, used by the dry-run cost
+        # extraction (XLA cost_analysis counts a scan body only once).
+        cache_list = []
+        for i in range(jax.tree.leaves(layers)[0].shape[0]):
+            unit = jax.tree.map(lambda a: a[i], layers)
+            carry, c = body_fn(carry, unit)
+            cache_list.append(c)
+        return carry, jax.tree.map(lambda *xs: jnp.stack(xs), *cache_list)
+
+    aux = jnp.zeros((), jnp.float32)
+    count = jnp.zeros((), jnp.int32)
+    lead_caches = None
+    if "lead" in params:
+        (x, aux, count), lead_caches = stack(x, params["lead"], True)
+    (x, aux_u, count_u), caches = stack(x, params["layers"], False)
+    if lead_caches is not None:
+        caches = dict(caches, lead=lead_caches)
+    return x, caches, aux + aux_u, count + count_u
+
+
 def forward(params, batch: dict, cfg: ArchConfig):
     """Full-sequence forward (train / prefill).
 
@@ -217,27 +284,7 @@ def forward(params, batch: dict, cfg: ArchConfig):
     x = embed_inputs(params, batch, cfg)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-
-    def body(carry, unit_params):
-        x, aux = carry
-        x, caches, aux_u = _unit_forward(unit_params, x, cfg, positions)
-        return (x, aux + aux_u), caches
-
-    body_fn = jax.checkpoint(body) if cfg.remat == "block" else body
-    if cfg.scan_layers:
-        (x, aux), caches = jax.lax.scan(
-            body_fn, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    else:
-        # Unrolled path: identical math/params, used by the dry-run cost
-        # extraction (XLA cost_analysis counts a scan body only once).
-        carry = (x, jnp.zeros((), jnp.float32))
-        cache_list = []
-        for i in range(_num_units(cfg)):
-            unit = jax.tree.map(lambda a: a[i], params["layers"])
-            carry, c = body_fn(carry, unit)
-            cache_list.append(c)
-        x, aux = carry
-        caches = jax.tree.map(lambda *xs: jnp.stack(xs), *cache_list)
+    x, caches, aux, _ = layer_stack(params, x, cfg, positions)
     return _logits(params, x, cfg), caches, aux
 
 
@@ -247,22 +294,29 @@ def decode_step(params, caches, tokens: jnp.ndarray, pos, cfg: ArchConfig,
     frontend tokens included for VLM). Returns (logits [B,1,V], caches)."""
     x = embed(params["embed"], tokens, cfg.embed_scale)
 
-    def body(x, inp):
-        unit_params, unit_cache = inp
-        x, new_cache = _unit_decode(unit_params, x, unit_cache, pos, cfg,
-                                    cache_mode)
-        return x, new_cache
+    def stack(x, layers, stack_caches, lead):
+        def body(x, inp):
+            unit_params, unit_cache = inp
+            x, new_cache = _unit_decode(unit_params, x, unit_cache, pos, cfg,
+                                        cache_mode, lead)
+            return x, new_cache
 
-    if cfg.scan_layers:
-        x, new_caches = jax.lax.scan(body, x, (params["layers"], caches))
-    else:
+        if cfg.scan_layers:
+            return jax.lax.scan(body, x, (layers, stack_caches))
         cache_list = []
-        for i in range(_num_units(cfg)):
-            unit = jax.tree.map(lambda a: a[i], params["layers"])
-            cache_u = jax.tree.map(lambda a: a[i], caches)
+        for i in range(jax.tree.leaves(layers)[0].shape[0]):
+            unit = jax.tree.map(lambda a: a[i], layers)
+            cache_u = jax.tree.map(lambda a: a[i], stack_caches)
             x, c = body(x, (unit, cache_u))
             cache_list.append(c)
-        new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *cache_list)
+        return x, jax.tree.map(lambda *xs: jnp.stack(xs), *cache_list)
+
+    unit_caches = {k: v for k, v in caches.items() if k != "lead"}
+    if "lead" in params:
+        x, lead_caches = stack(x, params["lead"], caches["lead"], True)
+    x, new_caches = stack(x, params["layers"], unit_caches, False)
+    if "lead" in params:
+        new_caches = dict(new_caches, lead=lead_caches)
     return _logits(params, x, cfg), new_caches
 
 
@@ -273,7 +327,7 @@ def init_cache(cfg: ArchConfig, batch: int, s_cache: int,
     units = _num_units(cfg)
     kinds = _block_kinds(cfg)
 
-    def leaf(kind):
+    def leaf(kind, units=units):
         if kind == "attn":
             if cfg.attention == "mla":
                 # (MLA latents are already rank-compressed; int8 not applied)
@@ -300,7 +354,10 @@ def init_cache(cfg: ArchConfig, batch: int, s_cache: int,
             ssm=jnp.zeros((units, batch, cfg.ssm_heads, cfg.ssm_state,
                            cfg.ssm_head_dim), jnp.float32))
 
-    return {f"sub{i}": leaf(kind) for i, kind in enumerate(kinds)}
+    caches = {f"sub{i}": leaf(kind) for i, kind in enumerate(kinds)}
+    if cfg.first_k_dense:
+        caches["lead"] = {"sub0": leaf("attn", cfg.first_k_dense)}
+    return caches
 
 
 def cache_length(cfg: ArchConfig, seq_len: int) -> int:

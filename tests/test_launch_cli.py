@@ -91,3 +91,28 @@ def test_compiled_async_still_rejects_controller(monkeypatch, capsys):
                               "--controller", "resid"])
     assert exc.value.code == 2
     assert "per barrier" in capsys.readouterr().err
+
+
+# ------------------------------------------------------- backbone learner
+@pytest.mark.parametrize("argv", [["--learner", "backbone"],
+                                  ["--dataset", "notes"],
+                                  ["--dataset", "notes", "--learner",
+                                   "backbone"]],
+                         ids=["no-notes", "no-backbone", "no-arch"])
+def test_backbone_learner_needs_notes_and_arch(monkeypatch, capsys, argv):
+    with pytest.raises(SystemExit) as exc:
+        run_cli(monkeypatch, argv)
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert "--learner backbone" in err
+
+
+def test_backbone_learner_runs_compiled(monkeypatch, capsys):
+    """A text agent (deepseek-v2-lite at its smoke-test size) beside a
+    chart MLP, through the compiled backend."""
+    run_cli(monkeypatch, ["--dataset", "notes", "--learner", "backbone",
+                          "--arch", "deepseek-v2-lite", "--reduced",
+                          "--steps", "2", "--rounds", "1", "--n", "64",
+                          "--backend", "compiled"])
+    line = capsys.readouterr().out.splitlines()[0]
+    assert line.startswith("notes,ascii,metered,compiled,rounds=")
