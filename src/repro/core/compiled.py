@@ -258,11 +258,11 @@ def plan_for(learners: Sequence, num_classes: int, *, max_rounds: int = 20,
 # ==================================================================== lowering
 #: Trace-entry counters keyed by program family (``session``,
 #: ``async_session``, ``serve``, ``serve_batch``, ``fleet``, ``sweep``,
-#: ``sweep_serve``, ``control_sweep``, ``extract``; and, for a learner core
-#: with a ``trace_family`` such as the neural backbone's, ``<family>_fit``
-#: and ``<family>_predict`` for each session program its hops are traced
-#: into): one increment each time a family's program is traced, none per
-#: call.  A correctly cached program traces once however
+#: ``sweep_serve``, ``control_sweep``, ``extract``, ``replay``; and, for a
+#: learner core with a ``trace_family`` such as the neural backbone's,
+#: ``<family>_fit`` and ``<family>_predict`` for each session program its
+#: hops are traced into): one increment each time a family's program is
+#: traced, none per call.  A correctly cached program traces once however
 #: often it runs, and a sweep once however many configs it vmaps over;
 #: ``Telemetry.sync_gauges`` exports the totals.
 TRACE_COUNTS: dict = {}
@@ -1524,6 +1524,30 @@ def extract_params(params: tuple) -> tuple:
     return _extract_program(treedef, avals)(params)
 
 
+def _row_slices(x):
+    """Every length-n row of ``x`` [..., n], nested over its leading axes."""
+    if x.ndim == 1:
+        return x
+    return tuple(_row_slices(x[i]) for i in range(x.shape[0]))
+
+
+@functools.lru_cache(maxsize=64)
+def _split_program(shape: tuple, dtype):
+    return jax.jit(_counted("replay", _row_slices))
+
+
+def split_rows(x: jnp.ndarray) -> tuple:
+    """Split a result's stacked score vectors (``w_trace`` [T, M, n],
+    ``w_bar`` [T, n]) into nested tuples over the leading axes, so
+    ``split_rows(w_trace)[t][j]`` is its own device array, bit-identical to
+    ``w_trace[t, j]``.  One program launch for every row (an eager slice
+    per row costs a host dispatch each); every row, not the sent ones only,
+    so the output count is static and the program does not retrace per
+    send pattern; cached per (shape, dtype), so a configuration traces it
+    once."""
+    return _split_program(tuple(x.shape), x.dtype)(x)
+
+
 def agent_major_result(result: SessionResult) -> SessionResult:
     """Re-collect a slot-major :class:`SessionResult` to agent-major.
 
@@ -1572,7 +1596,9 @@ def fitted_from_result(plan: SessionPlan, result: SessionResult,
     order exactly.  Component parameters come from one launch of the
     extraction program (:func:`extract_params`); the result's scalars reach
     the host in one batched fetch, whose host copies the ledger replay then
-    reads again for free."""
+    reads again for free.  The replay's ``IgnoranceMsg`` payloads come from
+    one launch of the row-split program (:func:`split_rows`), not from
+    here."""
     from repro.core.engine import Component, FittedASCII
 
     # the fetch waits for the session to finish, so the copies the launch

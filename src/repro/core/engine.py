@@ -1506,11 +1506,14 @@ class Protocol:
         extraction program, plus a slice per (round, leaf) and a stack per
         leaf where a permuting plan needs the agent-major view), and
         ``replay`` (the ledger; ``messages``, the entries it books on a
-        metered transport).  Where the learners count their work (the
-        neural backbone), the ``session`` span also carries the executed
-        hops' sums: ``tokens_fit``, ``tokens_predict`` and
-        ``expert_tokens`` (split as ``expert_tokens_fit`` and
-        ``expert_tokens_predict``), the tokens routed to held experts."""
+        metered transport, and ``dispatches``, the device programs it
+        launched: the one row-split program that gives every booked
+        ``IgnoranceMsg`` its payload, whatever the hop count).  Where the
+        learners count their work (the neural backbone), the ``session``
+        span also carries the executed hops' sums: ``tokens_fit``,
+        ``tokens_predict`` and ``expert_tokens`` (split as
+        ``expert_tokens_fit`` and ``expert_tokens_predict``), the tokens
+        routed to held experts."""
         from repro.core import compiled
         if not isinstance(self.variant, ASCIIVariant):
             self._attach_telemetry()
@@ -1565,9 +1568,11 @@ class Protocol:
         log = getattr(self.transport, "log", None)
         with self._span("replay", backend="compiled") as span:
             booked = 0 if log is None else len(log.entries)
-            replay(endpoints, classes, result, plan)
-            if span is not None and log is not None:
-                span.attrs["messages"] = len(log.entries) - booked
+            dispatches = replay(endpoints, classes, result, plan)
+            if span is not None:
+                span.attrs["dispatches"] = dispatches
+                if log is not None:
+                    span.attrs["messages"] = len(log.entries) - booked
         self._compiled_ctx = (tuple(endpoints), plan, kept)
         return fitted
 
@@ -1642,13 +1647,19 @@ class Protocol:
             scheduler=sched_plan)
 
     def _replay_traffic(self, endpoints: Sequence[AgentEndpoint],
-                        classes: jnp.ndarray, result, plan=None) -> None:
+                        classes: jnp.ndarray, result, plan=None) -> int:
         """Book the message ledger a sequential eager run would have
         produced: collation setup, then one IgnoranceMsg + ModelWeightMsg
         per component-producing hop, in chain order — at the *encoded* size
         of whichever codec rung the scan shipped each hop with, skipping
         budget-dropped hops, and tallying the privacy accountant, so the
-        compiled ledger is byte-identical to the eager one."""
+        compiled ledger is byte-identical to the eager one.
+
+        Each IgnoranceMsg carries row ``[t, j]`` of ``result.w_trace``, all
+        rows cut by one launch of :func:`repro.core.compiled.split_rows`
+        (an eager slice per hop costs a host dispatch each).  Returns the
+        device programs launched: 1."""
+        from repro.core import compiled
         self.transport.bind(endpoints)
         n = int(classes.shape[0])
         head = endpoints[0].name
@@ -1663,6 +1674,9 @@ class Protocol:
         codec_idx = np.asarray(result.codec_idx)
         order = getattr(result, "order", None)
         order = None if order is None else np.asarray(order)
+        # launched after the reads above wait for the session, so the copies
+        # never sit beside its working memory
+        w_rows = compiled.split_rows(result.w_trace)
         ladder = plan.ladder if plan is not None and plan.has_channel else None
         budget = plan.budget if plan is not None else None
         budgeted = budget is not None and hasattr(self.transport,
@@ -1699,7 +1713,7 @@ class Protocol:
                 codec = ladder[int(codec_idx[t, j])] if ladder else None
                 wire_bits = codec.wire_bits(n) if codec is not None else None
                 self.transport.send(IgnoranceMsg(
-                    endpoints[src].name, dst.name, result.w_trace[t, j],
+                    endpoints[src].name, dst.name, w_rows[t][j],
                     wire_bits=wire_bits))
                 self.transport.send(ModelWeightMsg(
                     endpoints[src].name, dst.name, float(alphas[t, j])))
@@ -1707,14 +1721,20 @@ class Protocol:
                     self.transport.accountant.record(endpoints[src].name)
         if budgeted:
             self.transport.exhausted = bool(result.exhausted)
+        return 1
 
     def _replay_traffic_async(self, endpoints: Sequence[AgentEndpoint],
-                              classes: jnp.ndarray, result, plan) -> None:
+                              classes: jnp.ndarray, result, plan) -> int:
         """Book the ledger an eager async-stale run produces: channel-less,
         the per-agent mid-merge IgnoranceMsg + ModelWeightMsg pairs; with a
         wire channel, the raw per-agent alpha messages followed by the one
         per-barrier release (or its budget skip) — spend-first, rung
-        stamped, DP release tallied, byte-identical to the eager barrier."""
+        stamped, DP release tallied, byte-identical to the eager barrier.
+
+        The payloads (``w_trace[t, m]`` channel-less, else ``w_bar[t]``)
+        come from one launch of :func:`repro.core.compiled.split_rows`.
+        Returns the device programs launched: 1."""
+        from repro.core import compiled
         self.transport.bind(endpoints)
         n = int(classes.shape[0])
         head = endpoints[0].name
@@ -1728,6 +1748,8 @@ class Protocol:
         rungs = np.asarray(result.codec_idx)
         num = len(endpoints)
         channel = plan.has_channel
+        rows = compiled.split_rows(result.w_bar if channel
+                                   else result.w_trace)
         budget = plan.budget
         budgeted = budget is not None and hasattr(self.transport,
                                                   "link_spent")
@@ -1740,7 +1762,7 @@ class Protocol:
                         continue
                     dst = endpoints[(m + 1) % num]
                     self.transport.send(IgnoranceMsg(
-                        endpoints[m].name, dst.name, result.w_trace[t, m]))
+                        endpoints[m].name, dst.name, rows[t][m]))
                     self.transport.send(ModelWeightMsg(
                         endpoints[m].name, dst.name, float(alphas[t, m])))
                 continue
@@ -1760,12 +1782,13 @@ class Protocol:
                     link, budget.payload_costs(n)[rung], rung)
             wire_bits = codec.wire_bits(n) if codec is not None else None
             self.transport.send(IgnoranceMsg(
-                "barrier", endpoints[0].name, result.w_bar[t],
+                "barrier", endpoints[0].name, rows[t],
                 wire_bits=wire_bits))
             if self.transport.privacy is not None:
                 self.transport.accountant.record("barrier")
         if budgeted:
             self.transport.exhausted = bool(result.exhausted)
+        return 1
 
     # ---- serve path ---------------------------------------------------------
     def predict_distributed(self, Xs: Sequence[jnp.ndarray] | None = None,

@@ -13,6 +13,7 @@ from program_tolerance import assert_floats_close, assert_history_close
 from repro.comm import (BudgetSpec, BudgetedTransport, GaussianMechanism,
                         make_codec)
 from repro.comm.codecs import Fp16Codec, QuantCodec
+from repro.control import AdaptiveController
 from repro.core.compiled import compiled_session, plan_for, quant_sweep_run
 from repro.core.engine import (AsyncStaleScheduler, MeteredTransport,
                                Protocol, SessionConfig, endpoints_for)
@@ -133,6 +134,51 @@ def test_compiled_matches_eager_budget_plus_privacy(blob):
     assert te_.accountant.releases == tc.accountant.releases
     assert te_.link_spent == tc.link_spent
     assert te_.exhausted == tc.exhausted
+
+
+REPLAY_RUNS = {
+    # each hop priced at its own rung of the adaptive ladder
+    "adaptive": (lambda: MeteredTransport(controller=AdaptiveController(
+        ladder=(Fp16Codec(), QuantCodec(bits=4)), thresholds=(0.5,),
+        beta=0.0)), dict(max_rounds=3)),
+    # codec-less: priced through num_elements, so the payload's size counts
+    "plain": (MeteredTransport, dict(max_rounds=3)),
+    # the budget degrades the ladder, then skips hops
+    "budget": (lambda: BudgetedTransport(BudgetSpec(session_bits=48_000)),
+               dict(max_rounds=5, stop_on_negative_alpha=False)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(REPLAY_RUNS))
+def test_replay_books_the_scan_rows(blob, name):
+    """The compiled replay books the eager ledger, and each endpoint's
+    freshest IgnoranceMsg carries, bit for bit, row ``w_trace[t, j]`` of
+    the last hop delivered to it, as its own device array."""
+    Xtr, ctr, _, _, k = blob
+    make, cfg_kw = REPLAY_RUNS[name]
+    cfg = SessionConfig(num_classes=k, **cfg_kw)
+    runs = {}
+    for backend in ("eager", "compiled"):
+        transport = make()
+        eps = endpoints_for([LogisticRegression(steps=40) for _ in Xtr], Xtr)
+        proto = Protocol(cfg, transport=transport, backend=backend)
+        proto.fit(jax.random.key(11), eps, ctr)
+        runs[backend] = proto, transport, eps
+    proto, tc, eps = runs["compiled"]
+    assert tc.log.entries == runs["eager"][1].log.entries
+    res = proto.compiled_result
+    booked = np.asarray(res.valid) & np.asarray(res.sent)
+    last = {}
+    for t, j in zip(*np.nonzero(booked)):     # row-major: chain order
+        last[(j + 1) % len(eps)] = t, j
+    assert len(last) == len(eps)
+    for dst, (t, j) in last.items():
+        w = eps[dst].latest("ignorance").w
+        assert isinstance(w, jax.Array)
+        assert np.asarray(w).tobytes() == \
+            np.asarray(res.w_trace[t, j]).tobytes()
+    if name == "budget":
+        assert tc.skipped
 
 
 def test_budget_per_link_cap(blob):

@@ -6,11 +6,12 @@ children are ``plan`` (transport attach, scheduler bind, ``plan_for``),
 ``session`` (the fenced compiled call, with ``traced``: the programs traced
 during it), ``extract`` (the fitted ensemble and the agent-major view, with
 ``dispatches``: the device programs it launched) and
-``replay`` (the ledger); on the eager backend ``session`` -> ``round`` ->
-``hop``.  In the compiled round body the model weight and reweight carry an
-``ascii_update_<j>`` scope and the wire channel an ``ascii_channel_<j>``
-scope, siblings of the hop's ``ascii_hop_<j>``.  ``TRACE_COUNTS`` counts the
-traces of every program family.
+``replay`` (the ledger, with ``dispatches``: the one row-split program
+that cuts every booked payload); on the eager backend ``session`` ->
+``round`` -> ``hop``.  In the compiled round body the model weight and
+reweight carry an ``ascii_update_<j>`` scope and the wire channel an
+``ascii_channel_<j>`` scope, siblings of the hop's ``ascii_hop_<j>``.
+``TRACE_COUNTS`` counts the traces of every program family.
 """
 import re
 import sys
@@ -90,6 +91,7 @@ def test_compiled_fit_span_tree(blob):
     assert by["extract"].attrs["leaves"] == 2 * len(fitted.components)
     assert by["extract"].attrs["dispatches"] == 1
     assert by["replay"].attrs["messages"] == len(transport.log.entries)
+    assert by["replay"].attrs["dispatches"] == 1
 
 
 def test_eager_fit_span_tree(blob):
@@ -133,6 +135,20 @@ def test_extract_is_one_program_traced_once(blob):
     assert [s.attrs["dispatches"] for s in spans] == [1, 1]
     assert [s.attrs["leaves"] for s in spans] == \
            [2 * len(f.components) for f in fits]
+
+
+def test_replay_is_one_program_traced_once(blob):
+    """Every fit of one configuration cuts the payloads its replay books
+    with one launch of one row-split program, traced by the first fit
+    only."""
+    compiled._split_program.cache_clear()
+    compiled.TRACE_COUNTS.clear()
+    tele = Telemetry()
+    for k in (1, 2):
+        _fit(blob, "compiled", tele, key=k)
+    assert compiled.TRACE_COUNTS["replay"] == 1
+    spans = [s for s in tele.tracer.spans if s.name == "replay"]
+    assert [s.attrs["dispatches"] for s in spans] == [1, 1]
 
 
 def test_session_program_scopes_update_and_channel(blob):
@@ -223,11 +239,13 @@ def _family_runs(blob):
                               betas=[0.0, 0.5])),
         "extract": (compiled._extract_program, result,
                     lambda res: compiled.extract_params(res.params)),
+        "replay": (compiled._split_program, result,
+                   lambda res: compiled.split_rows(res.w_trace)),
     }
 
 
 FAMILIES = ("session", "async_session", "serve", "serve_batch", "fleet",
-            "sweep", "sweep_serve", "control_sweep", "extract")
+            "sweep", "sweep_serve", "control_sweep", "extract", "replay")
 
 
 @pytest.mark.parametrize("family", FAMILIES)
