@@ -21,12 +21,14 @@ budget-aware round scheduling, RDP privacy accounting — lives in
 """
 from repro.comm.codecs import (CODECS, Codec, Fp16Codec, Fp32Codec,
                                QuantCodec, TopKCodec, channel_apply,
-                               jitted_channel, make_codec, serve_key)
+                               jitted_channel, make_codec, rung_bits,
+                               serve_key)
 from repro.comm.privacy import GaussianMechanism, PrivacyAccountant
 
 __all__ = [
     "CODECS", "Codec", "Fp16Codec", "Fp32Codec", "QuantCodec", "TopKCodec",
-    "channel_apply", "jitted_channel", "make_codec", "serve_key",
+    "channel_apply", "jitted_channel", "make_codec", "rung_bits",
+    "serve_key",
     "GaussianMechanism", "PrivacyAccountant",
     # lazy (avoids importing the engine on package import):
     "BudgetSpec", "BudgetedTransport", "DEFAULT_LADDER", "MODEL_WEIGHT_BITS",

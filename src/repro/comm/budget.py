@@ -29,11 +29,9 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 
-from repro.comm.codecs import Codec, Fp16Codec, Fp32Codec, QuantCodec
+from repro.comm.codecs import (MODEL_WEIGHT_BITS, Codec, Fp16Codec,
+                               Fp32Codec, QuantCodec, rung_bits)
 from repro.core.engine import MeteredTransport
-
-#: The scalar ModelWeightMsg that accompanies every shipped hop.
-MODEL_WEIGHT_BITS = 32
 
 DEFAULT_LADDER = (Fp32Codec(), Fp16Codec(), QuantCodec(bits=8),
                   QuantCodec(bits=4))
@@ -67,18 +65,14 @@ class BudgetSpec:
     def hop_costs(self, n: int) -> tuple:
         """Per-ladder-rung cost of one hop for a length-n score: the encoded
         IgnoranceMsg plus the scalar ModelWeightMsg."""
-        return tuple(c.wire_bits(n) + MODEL_WEIGHT_BITS for c in self.ladder)
+        return rung_bits(self.ladder, n, MODEL_WEIGHT_BITS)
 
     def payload_costs(self, shape) -> tuple:
         """Per-ladder-rung encoded size of one bare payload of ``shape`` —
-        no accompanying ModelWeightMsg: serve-path ScoreBlockMsgs and
-        protocol-variant traffic (GradientMsg / ResidualMsg) alike."""
-        return tuple(c.wire_bits(shape) for c in self.ladder)
-
-    def serve_costs(self, shape) -> tuple:
-        """Per-ladder-rung cost of one prediction-time ScoreBlockMsg for an
-        [n, K] block — no accompanying ModelWeightMsg on the serve path."""
-        return self.payload_costs(shape)
+        no accompanying ModelWeightMsg: serve-path ScoreBlockMsgs, the
+        async barrier release and protocol-variant traffic (GradientMsg /
+        ResidualMsg) alike."""
+        return rung_bits(self.ladder, shape)
 
     def choose_costs(self, costs, remaining_session: float,
                      remaining_link: float, floor: int = 0) -> int | None:
@@ -94,12 +88,6 @@ class BudgetSpec:
             if costs[i] <= remaining:
                 return i
         return None
-
-    def choose(self, n: int, remaining_session: float,
-               remaining_link: float, floor: int = 0) -> int | None:
-        """:meth:`choose_costs` over the training-hop cost table."""
-        return self.choose_costs(self.hop_costs(n), remaining_session,
-                                 remaining_link, floor)
 
 
 @dataclass
@@ -145,18 +133,13 @@ class BudgetedTransport(MeteredTransport):
 
     def __init__(self, budget: BudgetSpec, log=None, privacy=None,
                  controller=None, accountant=None, serve_controller=None):
-        if controller is not None and \
-                tuple(controller.ladder) != tuple(budget.ladder):
-            raise ValueError(
-                "an adaptive controller on a budgeted transport must share "
-                "the budget's ladder (its rung is a floor on the same walk); "
-                f"got {controller.ladder} vs {budget.ladder}")
-        if serve_controller is not None and \
-                tuple(serve_controller.ladder) != tuple(budget.ladder):
-            raise ValueError(
-                "a serve controller on a budgeted transport must share the "
-                "budget's ladder (its rung is a floor on the serve walk); "
-                f"got {serve_controller.ladder} vs {budget.ladder}")
+        for what, ctl in (("an adaptive", controller),
+                          ("a serve", serve_controller)):
+            if ctl is not None and tuple(ctl.ladder) != tuple(budget.ladder):
+                raise ValueError(
+                    f"{what} controller on a budgeted transport must share "
+                    f"the budget's ladder (its rung is a floor on the walk); "
+                    f"got {ctl.ladder} vs {budget.ladder}")
         super().__init__(log=log,
                          codec=None if controller is not None
                          else budget.ladder[0],
@@ -167,21 +150,17 @@ class BudgetedTransport(MeteredTransport):
         self.link_spent: dict = {}      # (src, dst) -> bits
         self.skipped: list = []         # (src, dst) of dropped hops
         self.exhausted = False
-        # rung chosen by the most recent ladder walk, consumed by the next
-        # wire-priced booking (_on_send stamps it onto the ledger entry so a
-        # late-attached registry can backfill hops_by_rung_total)
-        self._pending_rung: int | None = None
         # bits a paused run already spent against the session cap (restored
         # from SessionState.comm on resume; this process's log starts empty)
         self.carryover_bits = 0
 
     # ------------------------------------------------------- budget ledger
-    # Every skip and every spend — eager ladder walks here, compiled ledger
-    # replays in Protocol._replay_traffic/_replay_serve and the scenario
-    # _replay — goes through these two methods, so the telemetry registry
-    # (attached to self.log) sees identical budget traffic on both backends.
+    # Every skip and every spend — eager ladder walks and compiled ledger
+    # replays alike — reaches these two methods through Transport.book_hop /
+    # skip_hop, so the telemetry registry (attached to self.log) sees
+    # identical budget traffic on both backends.
 
-    def record_skip(self, link) -> None:
+    def skip_hop(self, link) -> None:
         """Book one dropped hop on ``link`` = (src, dst)."""
         self.skipped.append(link)
         registry = getattr(self.log, "registry", None)
@@ -190,19 +169,43 @@ class BudgetedTransport(MeteredTransport):
 
     def record_spend(self, link, cost: int, rung: int) -> None:
         """Book ``cost`` bits of link spend for a hop shipped at ladder
-        index ``rung``.  Arms ``_pending_rung`` so the wire-priced booking
-        that follows (eager: the send inside ``super().interchange`` /
-        ``serve_block`` / ``ship``; compiled: the replayed send right after
-        this call) records the rung on its ledger entry.  Also degrades
-        ``codec`` to the chosen rung — the single place both backends set
-        it, so a replayed run ends with the same last-used codec as the
-        eager walk."""
+        index ``rung`` (``book_hop`` calls it before the payload's send).
+        Also sets ``codec`` to the chosen rung, so a replayed run ends with
+        the same last-used codec as the eager walk."""
         self.codec = self.budget.ladder[int(rung)]
         self.link_spent[link] = self.link_spent.get(link, 0) + cost
-        self._pending_rung = int(rung)
         registry = getattr(self.log, "registry", None)
         if registry is not None:
             registry.inc("hops_by_rung_total", 1, rung=int(rung))
+
+    def remaining(self, link) -> tuple:
+        """The (session, link) bits left under the caps for a hop on
+        ``link``; ``math.inf`` where uncapped."""
+        b = self.budget
+        return ((math.inf if b.session_bits is None
+                 else b.session_bits - self.log.total_bits
+                 - self.carryover_bits),
+                (math.inf if b.link_bits is None
+                 else b.link_bits - self.link_spent.get(link, 0)))
+
+    def _walk(self, link, costs, floor: int = 0, *, link_capped=True):
+        """The one eager ladder walk: the ``(rung, cost)`` the hop on
+        ``link`` ships at, with ``codec`` degraded to that rung for the
+        channel; or None once the hop is booked as a skip (the receiver
+        keeps its stale score), a session-budget skip flipping
+        ``exhausted`` so round scheduling ends.  ``link_capped=False``
+        exempts a hop with no directed link (the barrier broadcast) from
+        the link cap."""
+        rem_s, rem_l = self.remaining(link)
+        rung = self.budget.choose_costs(
+            costs, rem_s, rem_l if link_capped else math.inf, floor)
+        if rung is None:
+            if rem_s < min(costs):
+                self.exhausted = True
+            self.skip_hop(link)
+            return None
+        self.codec = self.budget.ladder[rung]
+        return rung, costs[rung]
 
     def _choose_codec(self, w_prev, w_out) -> None:
         # rung choice already happened in interchange (the controller floor
@@ -222,7 +225,6 @@ class BudgetedTransport(MeteredTransport):
 
     def interchange(self, src, dst, w, r, alpha, reweight,
                     standard=True, *, key=None, codec_state=None):
-        n = int(w.shape[0])
         floor, w_out = 0, None
         if self.controller is not None:
             # observe the hop the way the base hook would: the controller
@@ -230,25 +232,14 @@ class BudgetedTransport(MeteredTransport):
             # once here and threaded through to the base interchange
             w_out = self._execute_update(w, r, alpha, reweight, standard)
             floor = self._controller_rung(w, w_out)
-        costs = self.budget.hop_costs(n)
-        link = (src.name, dst.name)
-        rem_s = (math.inf if self.budget.session_bits is None
-                 else self.budget.session_bits - self.log.total_bits
-                 - self.carryover_bits)
-        rem_l = (math.inf if self.budget.link_bits is None
-                 else self.budget.link_bits - self.link_spent.get(link, 0))
-        idx = self.budget.choose(n, rem_s, rem_l, floor)
-        if idx is None:
-            # defer/skip: the hop is dropped, the receiver keeps its stale
-            # score; a session-budget skip ends round scheduling
-            if rem_s < min(costs):
-                self.exhausted = True
-            self.record_skip(link)
+        spend = self._walk((src.name, dst.name),
+                           self.budget.hop_costs(int(w.shape[0])), floor)
+        if spend is None:
             return w, codec_state
-        self.record_spend(link, costs[idx], idx)   # degrades codec too
         return super().interchange(src, dst, w, r, alpha, reweight,
                                    standard, key=key,
-                                   codec_state=codec_state, _w_out=w_out)
+                                   codec_state=codec_state, _w_out=w_out,
+                                   _spend=spend)
 
     def serve_block(self, src, dst, block, *, key=None):
         """Budgeted serve hop: the same degrade-then-skip ladder walk as
@@ -257,8 +248,6 @@ class BudgetedTransport(MeteredTransport):
         agent's votes (head-only degradation) and no bits are booked; a
         session-budget skip flips ``exhausted`` exactly like a training
         hop."""
-        shape = tuple(block.shape)
-        costs = self.budget.serve_costs(shape)
         floor = 0
         if self.serve_controller is not None:
             # the serve policy's rung floors the walk, exactly like the
@@ -266,20 +255,12 @@ class BudgetedTransport(MeteredTransport):
             # degrade coarser than the policy asked for, never finer
             from repro.control.adaptive import jitted_serve_controller
             floor = int(jitted_serve_controller(self.serve_controller)(block))
-        link = (src.name, dst.name)
-        rem_s = (math.inf if self.budget.session_bits is None
-                 else self.budget.session_bits - self.log.total_bits
-                 - self.carryover_bits)
-        rem_l = (math.inf if self.budget.link_bits is None
-                 else self.budget.link_bits - self.link_spent.get(link, 0))
-        idx = self.budget.choose_costs(costs, rem_s, rem_l, floor)
-        if idx is None:
-            if rem_s < min(costs):
-                self.exhausted = True
-            self.record_skip(link)
+        spend = self._walk((src.name, dst.name),
+                           self.budget.payload_costs(tuple(block.shape)),
+                           floor)
+        if spend is None:
             return None
-        self.record_spend(link, costs[idx], idx)   # degrades codec too
-        return super().serve_block(src, dst, block, key=key)
+        return super().serve_block(src, dst, block, key=key, _spend=spend)
 
     def barrier_release(self, head, w_bar, *, key=None, codec_state=None):
         """Budgeted async-barrier release: one *session-level* ladder walk
@@ -288,21 +269,13 @@ class BudgetedTransport(MeteredTransport):
         is a broadcast, not a directed link).  A skip leaves the published
         score stale and flips ``exhausted``, ending round scheduling —
         per-barrier budget metering on the one ledger."""
-        n = int(w_bar.shape[0])
-        costs = self.budget.payload_costs(n)
-        rem_s = (math.inf if self.budget.session_bits is None
-                 else self.budget.session_bits - self.log.total_bits
-                 - self.carryover_bits)
-        idx = self.budget.choose_costs(costs, rem_s, math.inf)
-        link = ("barrier", head.name)
-        if idx is None:
-            if rem_s < min(costs):
-                self.exhausted = True
-            self.record_skip(link)
+        spend = self._walk(("barrier", head.name),
+                           self.budget.payload_costs(int(w_bar.shape[0])),
+                           link_capped=False)
+        if spend is None:
             return None, codec_state
-        self.record_spend(link, costs[idx], idx)   # degrades codec too
         return super().barrier_release(head, w_bar, key=key,
-                                       codec_state=codec_state)
+                                       codec_state=codec_state, _spend=spend)
 
     def ship(self, src, dst, payload, wrap, *, key=None):
         """Budgeted protocol-variant hop (GradientMsg / ResidualMsg): the
@@ -312,19 +285,8 @@ class BudgetedTransport(MeteredTransport):
         this client; AL: the next agent fits yesterday's residual) — and a
         session-budget skip flips ``exhausted`` so the engine stops
         scheduling rounds."""
-        shape = tuple(payload.shape)
-        costs = self.budget.payload_costs(shape)
-        link = (src.name, dst.name)
-        rem_s = (math.inf if self.budget.session_bits is None
-                 else self.budget.session_bits - self.log.total_bits
-                 - self.carryover_bits)
-        rem_l = (math.inf if self.budget.link_bits is None
-                 else self.budget.link_bits - self.link_spent.get(link, 0))
-        idx = self.budget.choose_costs(costs, rem_s, rem_l)
-        if idx is None:
-            if rem_s < min(costs):
-                self.exhausted = True
-            self.record_skip(link)
+        spend = self._walk((src.name, dst.name),
+                           self.budget.payload_costs(tuple(payload.shape)))
+        if spend is None:
             return None
-        self.record_spend(link, costs[idx], idx)   # degrades codec too
-        return super().ship(src, dst, payload, wrap, key=key)
+        return super().ship(src, dst, payload, wrap, key=key, _spend=spend)

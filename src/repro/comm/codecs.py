@@ -73,6 +73,22 @@ def numel(shape) -> int:
     return int(shape)
 
 
+#: The scalar ModelWeightMsg that accompanies every shipped interchange hop.
+MODEL_WEIGHT_BITS = 32
+
+
+def rung_bits(ladder, shape, extra: int = 0) -> tuple[int, ...]:
+    """What one payload of ``shape`` costs on the wire at each rung of
+    ``ladder``: the codec's encoded ``wire_bits``, or 32 bits per element on
+    a None (raw fp32) rung, plus ``extra`` (the 32-bit ModelWeightMsg that
+    rides an interchange hop).  Equal to what ``Message.bits`` books for
+    the payload — the one price table behind the budget walk, the compiled
+    replays and the traced programs' spent-bit and live counters."""
+    raw = 32 * numel(shape)
+    return tuple((raw if c is None else int(c.wire_bits(shape))) + extra
+                 for c in ladder)
+
+
 @dataclass(frozen=True)
 class Codec(abc.ABC):
     """A pure encode/decode pair over float arrays: length-n ignorance

@@ -13,9 +13,9 @@ rides the ``SessionState.comm`` snapshot unchanged):
     ν = σ/clip has Rényi divergence ε_RDP(α) = α / (2ν²) at every order
     α > 1 (Mironov 2017, Prop. 7);
   * k releases compose *additively in RDP*: k·α / (2ν²) — the accountant
-    state is still just the per-agent release count, which is why the
-    compiled backend's post-run replay (`Protocol._replay_traffic`) and the
-    checkpoint snapshot need no changes;
+    state is still just the per-agent release count, which is why the one
+    wire ledger (`Transport.book_hop`, eager hops and compiled replays
+    alike) and the checkpoint snapshot need no changes;
   * conversion to (ε, δ) happens **on read**:
     ε(δ) = min_α [ k·α/(2ν²) + log(1/δ)/(α − 1) ] over a fixed order grid,
     reported at the mechanism's own δ.

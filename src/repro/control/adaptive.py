@@ -40,7 +40,7 @@ identically on both engine backends:
 
 Composition with a bit budget: the controller's rung is a *floor* on the
 ladder index — the budget walk may degrade further (coarser) when bits run
-low, never finer (``BudgetSpec.choose(..., floor=rung)``).
+low, never finer (``BudgetSpec.choose_costs(..., floor=rung)``).
 
 The EMA is protocol state: it rides the scan carry (compiled), lives on the
 transport between hops (eager), is snapshotted into ``SessionState.comm``

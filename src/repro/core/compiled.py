@@ -313,6 +313,24 @@ def ladder_walk(costs, rem, floor=None):
     return rung
 
 
+def rung_price(rung, prices):
+    """``prices[rung]`` as an int32 scalar, 0 at rung -1 (nothing shipped):
+    the traced read of a per-rung price table
+    (:func:`repro.comm.codecs.rung_bits`)."""
+    return jnp.select([rung == i for i in range(len(prices))],
+                      [jnp.asarray(c, jnp.int32) for c in prices],
+                      jnp.asarray(0, jnp.int32))
+
+
+def check_caps(budget) -> None:
+    """Raise unless ``budget``'s caps fit the programs' int32 spent-bit
+    counters."""
+    for cap in (budget.session_bits, budget.link_bits):
+        if cap is not None and cap >= _INT32_MAX:
+            raise ValueError(f"budget caps must fit int32 (the scan's "
+                             f"spent-bit counters), got {cap}")
+
+
 def rung_select(rung, values, default):
     """Pick ``values[rung]`` (with ``default`` at rung -1) — the payload
     half of the ladder walk, single-rung ladders short-circuiting exactly
@@ -400,59 +418,36 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
             raise ValueError("spend_signal='link' orders by budgeted link "
                              "spend, but the plan has no budget")
     if budget is not None and not control_arg:
-        for cap in (budget.session_bits, budget.link_bits):
-            if cap is not None and cap >= _INT32_MAX:
-                raise ValueError(f"budget caps must fit int32 (the scan's "
-                                 f"spent-bit counters), got {cap}")
+        check_caps(budget)
     num = plan.num_agents
 
     def session_fn(key: jax.Array, Xs: tuple, classes: jnp.ndarray,
                    qmax=None, cuts=None, beta=None, session_cap=None,
                    link_cap=None) -> SessionResult:
-        from repro.comm.codecs import channel_apply
+        from repro.comm.codecs import (MODEL_WEIGHT_BITS, channel_apply,
+                                       rung_bits)
+        from repro.core.engine import setup_bits
         classes = classes.astype(jnp.int32)
         n = classes.shape[0]
         onehot = jax.nn.one_hot(classes, k)
         reweight = _make_reweight(plan)
         w0 = scores.init_ignorance(n)
         ones = jnp.ones((n,), jnp.float32)
+        # one shipped hop's bits per rung (the ignorance payload plus the
+        # 32-bit ModelWeightMsg), the prices the replay books: the budget
+        # walk, the "wire" ordering signal (what TransportLog.bits_by_src
+        # tallies per sender) and the live counters all read this table,
+        # so none can drift from the eager metered ledger
+        hop_bits = rung_bits(ladder, n, MODEL_WEIGHT_BITS)
         if scheduler is not None:
             from repro.control.scheduler import (reward_ema_update,
                                                  traced_round_order)
             Xstack = jnp.stack(Xs)
-            if scheduler.spend_signal == "wire":
-                # the plain-metered ordering signal: each shipped hop's
-                # ignorance wire bits plus the 32-bit ModelWeightMsg —
-                # exactly what TransportLog.bits_by_src tallies per sender
-                wire_costs = tuple(
-                    (int(c.wire_bits(n)) if c is not None else n * 32) + 32
-                    for c in ladder)
         if budget is not None:
-            costs = tuple(jnp.asarray(c, jnp.int32)
-                          for c in budget.hop_costs(n))
-            min_cost = min(budget.hop_costs(n))
-            # setup spend priced by the Message classes themselves, so the
-            # scan's counter can never drift from the eager metered ledger
-            from repro.core.engine import LabelsMsg, SampleIdsMsg
-            setup_bits = (num - 1) * (LabelsMsg("", "", n).bits
-                                      + SampleIdsMsg("", "", n).bits)
+            costs = tuple(jnp.asarray(c, jnp.int32) for c in hop_bits)
+            min_cost = min(hop_bits)
         if live:
-            from repro.core.engine import LabelsMsg, SampleIdsMsg
             from repro.telemetry.live import emit_round, key_salt
-            live_setup = (num - 1) * (LabelsMsg("", "", n).bits
-                                      + SampleIdsMsg("", "", n).bits)
-            # per-hop priced bits by final rung (-1 = unsent -> 0): the
-            # replay's IgnoranceMsg wire/raw bits plus the 32-bit alpha
-            # message — identical formulas, so the live counters land
-            # exactly on the replay-booked ledger
-            if budget is not None:
-                live_hop_costs = tuple(int(c) for c in budget.hop_costs(n))
-            elif has_channel:
-                live_hop_costs = tuple(
-                    (int(c.wire_bits(n)) if c is not None else n * 32) + 32
-                    for c in ladder)
-            else:
-                live_hop_costs = (n * 32 + 32,)
 
         def round_body(carry, t_idx):
             w, key, stopped = carry["w"], carry["key"], carry["stopped"]
@@ -544,8 +539,8 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
                         # ---- the wire: controller/budget rung choice, DP
                         # noise, codec — the same decision rule and traced
                         # channel the eager transports run
-                        # (Transport._controller_rung / BudgetSpec.choose /
-                        # channel_apply)
+                        # (Transport._controller_rung,
+                        # BudgetSpec.choose_costs, channel_apply)
                         if controller is not None:
                             # branchless adaptive rung from (receiver's stale
                             # vector, outgoing vector); the EMA advances on
@@ -608,10 +603,7 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
                             carry["resid"] = carry["resid"].at[src].set(
                                 jnp.where(sent, pairs[0][1], state_j))
                         if budget is not None:
-                            cost = jnp.select(
-                                [rung == i for i in range(len(ladder))],
-                                list(costs), jnp.asarray(0, jnp.int32))
-                            add = jnp.where(sent, cost, 0)
+                            add = jnp.where(sent, rung_price(rung, costs), 0)
                             carry["spent"] = carry["spent"] + add
                             if scheduler is not None:
                                 carry["link"] = carry["link"].at[
@@ -626,21 +618,13 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
                         and scheduler.spend_signal == "wire":
                     # per-sender metered-ledger tally (ignorance wire bits
                     # + the 32-bit alpha message) for next round's ordering
-                    wcost = jnp.select(
-                        [rung == i for i in range(len(wire_costs))],
-                        [jnp.asarray(c, jnp.int32) for c in wire_costs],
-                        jnp.asarray(0, jnp.int32))
                     carry["wire"] = carry["wire"].at[src].add(
-                        jnp.where(sent, wcost, 0))
+                        jnp.where(sent, rung_price(rung, hop_bits), 0))
                 if live:
                     live_sent = live_sent + jnp.where(sent, 1, 0)
                     live_skip = live_skip + jnp.where(
                         valid & jnp.logical_not(sent), 1, 0)
-                    live_bits = live_bits + jnp.select(
-                        [rung == i for i in range(len(live_hop_costs))],
-                        [jnp.asarray(c, jnp.int32)
-                         for c in live_hop_costs],
-                        jnp.asarray(0, jnp.int32))
+                    live_bits = live_bits + rung_price(rung, hop_bits)
                 stopped = stopped | trigger
                 outs.append((params, a, rbar, executed, valid, w, sent,
                              rung, jnp.asarray(src, jnp.int32), counts))
@@ -655,7 +639,7 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
                     & jnp.logical_not(live_entry_exh), 1, 0)
                 emit_round(t_idx, live_active,
                            live_bits + jnp.where(t_idx == 0,
-                                                 live_setup, 0)
+                                                 setup_bits(num, n), 0)
                            + key_salt(key),
                            live_sent, live_skip, new_exh)
             carry = dict(carry, w=w, key=key, stopped=stopped)
@@ -667,7 +651,7 @@ def make_session_fn(plan: SessionPlan, feature_shapes: tuple,
         if controller is not None:
             init["ctrl"] = controller.init_state()
         if budget is not None:
-            init["spent"] = jnp.asarray(setup_bits, jnp.int32)
+            init["spent"] = jnp.asarray(setup_bits(num, n), jnp.int32)
             # per directed link under a permuting scheduler (any src->dst
             # pair can carry a hop), per chain slot otherwise
             init["link"] = (jnp.zeros((num, num), jnp.int32)
@@ -798,40 +782,26 @@ def make_async_session_fn(plan: SessionPlan, feature_shapes: tuple,
     has_channel = plan.has_channel
     stateful = codec is not None and codec.stateful
     if budget is not None:
-        for cap in (budget.session_bits, budget.link_bits):
-            if cap is not None and cap >= _INT32_MAX:
-                raise ValueError(f"budget caps must fit int32 (the scan's "
-                                 f"spent-bit counters), got {cap}")
+        check_caps(budget)
     num = plan.num_agents
 
     def session_fn(key: jax.Array, Xs: tuple,
                    classes: jnp.ndarray) -> AsyncSessionResult:
-        from repro.comm.codecs import channel_apply
+        from repro.comm.codecs import (MODEL_WEIGHT_BITS, channel_apply,
+                                       rung_bits)
+        from repro.core.engine import setup_bits
         classes = classes.astype(jnp.int32)
         n = classes.shape[0]
         onehot = jax.nn.one_hot(classes, k)
         w0 = scores.init_ignorance(n)
+        # the barrier release's bits per rung (a channel-less plan's ladder
+        # is the one raw rung): what the replay books for its IgnoranceMsg
+        bar_bits = rung_bits(ladder, n)
         if budget is not None:
-            costs = tuple(jnp.asarray(c, jnp.int32)
-                          for c in budget.payload_costs(n))
-            min_cost = min(budget.payload_costs(n))
-            from repro.core.engine import LabelsMsg, SampleIdsMsg
-            setup_bits = (num - 1) * (LabelsMsg("", "", n).bits
-                                      + SampleIdsMsg("", "", n).bits)
+            costs = tuple(jnp.asarray(c, jnp.int32) for c in bar_bits)
+            min_cost = min(bar_bits)
         if live:
-            from repro.core.engine import LabelsMsg, SampleIdsMsg
             from repro.telemetry.live import emit_round, key_salt
-            live_setup = (num - 1) * (LabelsMsg("", "", n).bits
-                                      + SampleIdsMsg("", "", n).bits)
-            if has_channel:
-                # the barrier release's priced bits per rung: what the
-                # async replay books for the single barrier IgnoranceMsg
-                live_bar_costs = (tuple(int(c) for c
-                                        in budget.payload_costs(n))
-                                  if budget is not None else
-                                  tuple(int(c.wire_bits(n))
-                                        if c is not None else n * 32
-                                        for c in ladder))
 
         def round_body(carry, t_idx):
             w, key, stopped = carry["w"], carry["key"], carry["stopped"]
@@ -880,7 +850,8 @@ def make_async_session_fn(plan: SessionPlan, feature_shapes: tuple,
                     # the raw alpha messages book before the walk reads
                     # the ledger (the eager merge loop sends them first);
                     # link caps don't apply — the barrier is session-level
-                    carry["spent"] = carry["spent"] + 32 * pos_count
+                    carry["spent"] = (carry["spent"]
+                                      + MODEL_WEIGHT_BITS * pos_count)
                     rem_s = jnp.asarray(_INT32_MAX, jnp.int32)
                     if budget.session_bits is not None:
                         rem_s = (jnp.asarray(budget.session_bits, jnp.int32)
@@ -906,11 +877,8 @@ def make_async_session_fn(plan: SessionPlan, feature_shapes: tuple,
                 if stateful:
                     carry["resid"] = jnp.where(sent, pairs[0][1], state)
                 if budget is not None:
-                    cost = jnp.select(
-                        [rung == i for i in range(len(ladder))],
-                        list(costs), jnp.asarray(0, jnp.int32))
-                    carry["spent"] = carry["spent"] + jnp.where(sent, cost,
-                                                                0)
+                    carry["spent"] = carry["spent"] + jnp.where(
+                        sent, rung_price(rung, costs), 0)
                 rung = jnp.where(sent, rung, -1)
             if plan.stop_on_negative_alpha:
                 stopped = stopped | (executed & jnp.logical_not(any_pos))
@@ -919,18 +887,15 @@ def make_async_session_fn(plan: SessionPlan, feature_shapes: tuple,
             if live:
                 if not has_channel:
                     # per positive agent: raw IgnoranceMsg + alpha message
-                    live_bits = pos_count * jnp.asarray(n * 32 + 32,
-                                                        jnp.int32)
+                    live_bits = pos_count * jnp.asarray(
+                        bar_bits[0] + MODEL_WEIGHT_BITS, jnp.int32)
                     live_ign = pos_count
                     live_skip = jnp.asarray(0, jnp.int32)
                 else:
                     # raw alpha messages per positive agent + the single
                     # barrier release at its priced rung
-                    live_bits = 32 * pos_count + jnp.select(
-                        [rung == i for i in range(len(live_bar_costs))],
-                        [jnp.asarray(c, jnp.int32)
-                         for c in live_bar_costs],
-                        jnp.asarray(0, jnp.int32))
+                    live_bits = (MODEL_WEIGHT_BITS * pos_count
+                                 + rung_price(rung, bar_bits))
                     live_ign = jnp.where(sent, 1, 0)
                     live_skip = (jnp.where(executed
                                            & jnp.logical_not(sent), 1, 0)
@@ -941,7 +906,7 @@ def make_async_session_fn(plan: SessionPlan, feature_shapes: tuple,
                     & jnp.logical_not(live_entry_exh), 1, 0)
                 emit_round(t_idx, executed,
                            live_bits + jnp.where(t_idx == 0,
-                                                 live_setup, 0)
+                                                 setup_bits(num, n), 0)
                            + key_salt(key),
                            live_ign, live_skip, new_exh)
             carry = dict(carry, w=w, key=key, stopped=stopped)
@@ -954,7 +919,7 @@ def make_async_session_fn(plan: SessionPlan, feature_shapes: tuple,
         if stateful:
             init["resid"] = jnp.zeros((n,), jnp.float32)
         if budget is not None:
-            init["spent"] = jnp.asarray(setup_bits, jnp.int32)
+            init["spent"] = jnp.asarray(setup_bits(num, n), jnp.int32)
             init["exhausted"] = jnp.zeros((), bool)
         if live:
             fin, (ys, w_bars, sents, rungs) = jax.lax.scan(
@@ -1140,11 +1105,13 @@ def make_serve_fn(plan: SessionPlan, feature_shapes: tuple,
 
     def serve_fn(key, Xs, params, alphas, valid, rem_session, rem_link,
                  deliver, qmax=None) -> ServeResult:
-        from repro.comm.codecs import channel_apply
+        from repro.comm.codecs import channel_apply, rung_bits
         n = int(Xs[0].shape[0])
         shape = (n, k)
+        # one shipped block's bits per rung: what the replay books for its
+        # ScoreBlockMsg, and what the budget walk and live counters read
+        costs = rung_bits(ladder, shape)
         if budget is not None:
-            costs = budget.serve_costs(shape)
             if max(costs) >= _INT32_MAX:
                 raise ValueError(f"serve block costs must fit int32 (the "
                                  f"budget counters), got {max(costs)}")
@@ -1153,13 +1120,6 @@ def make_serve_fn(plan: SessionPlan, feature_shapes: tuple,
         deliver = jnp.asarray(deliver, bool)
         if live:
             from repro.telemetry.live import emit_serve, key_salt
-            # per-block priced bits: what _replay_serve books for each
-            # shipped ScoreBlockMsg (encoded wire bits, raw 32*n*K when
-            # the serve rung is the identity)
-            live_costs = (tuple(int(c) for c in budget.serve_costs(shape))
-                          if budget is not None else
-                          tuple(int(c.wire_bits(shape)) if c is not None
-                                else 32 * n * k for c in ladder))
             live_bits = jnp.asarray(0, jnp.int32)
             live_sent = jnp.asarray(0, jnp.int32)
             live_skip = jnp.asarray(0, jnp.int32)
@@ -1217,10 +1177,8 @@ def make_serve_fn(plan: SessionPlan, feature_shapes: tuple,
                 pairs = [channel_apply(c, None, noised, sub, None)[0]
                          for c in ladder]
                 blk = rung_select(rung, pairs, block)
-                cost = jnp.select([rung == i for i in range(len(ladder))],
-                                  [jnp.asarray(c, jnp.int32) for c in costs],
-                                  jnp.asarray(0, jnp.int32))
-                rem_s = rem_s - jnp.where(sendable, cost, 0)
+                rem_s = rem_s - jnp.where(sendable, rung_price(rung, costs),
+                                          0)
                 contrib = jnp.where(sendable, blk, jnp.zeros_like(blk))
             elif serve_controller is not None:
                 # unbudgeted adaptive serve: noise once, per-rung
@@ -1248,12 +1206,9 @@ def make_serve_fn(plan: SessionPlan, feature_shapes: tuple,
                     live_skip = live_skip + jnp.where(
                         d_j & jnp.logical_not(sendable), 1, 0)
                 if budget is None and serve_controller is None:
-                    hop_cost = jnp.asarray(live_costs[0], jnp.int32)
+                    hop_cost = jnp.asarray(costs[0], jnp.int32)
                 else:
-                    hop_cost = jnp.select(
-                        [rung == i for i in range(len(live_costs))],
-                        [jnp.asarray(c, jnp.int32) for c in live_costs],
-                        jnp.asarray(0, jnp.int32))
+                    hop_cost = rung_price(rung, costs)
                 live_bits = live_bits + jnp.where(sendable, hop_cost, 0)
             blocks.append(blk)
             sent_l.append(sendable)

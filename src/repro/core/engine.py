@@ -57,7 +57,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import scores
+from repro.comm.codecs import (MODEL_WEIGHT_BITS, jitted_channel, rung_bits,
+                               serve_key)
+from repro.comm.privacy import PrivacyAccountant
+from repro.core import compiled, scores
 from repro.core.encoding import encode_labels
 from repro.core.transport import TransportLog
 from repro.learners.base import Learner
@@ -205,6 +208,27 @@ class SampleIdsMsg(Message):
         return self.num_samples
 
 
+def setup_bits(num_agents: int, n: int) -> int:
+    """Bits the collation setup books (:meth:`Transport.book_setup`): one
+    LabelsMsg and one SampleIdsMsg of ``n`` rows per non-head agent."""
+    return (num_agents - 1) * (LabelsMsg("", "", n).bits
+                               + SampleIdsMsg("", "", n).bits)
+
+
+def hop_prices(ladder, shape, *, budgeted: bool, extra: int = 0) -> tuple:
+    """Per rung of ``ladder``, what a replay books a shipped hop of
+    ``shape`` with: its payload message's ``wire_bits`` (the
+    :func:`~repro.comm.codecs.rung_bits` price, or None on a raw rung —
+    priced through ``num_elements``, as the eager channel leaves it) and,
+    when ``budgeted``, the ``spend`` for :meth:`Transport.book_hop`: the
+    rung and its cost, the payload plus ``extra`` (the alpha message's
+    bits on an interchange hop)."""
+    return tuple((None if c is None else bits,
+                  (i, bits + extra) if budgeted else None)
+                 for i, (c, bits) in enumerate(zip(ladder,
+                                                   rung_bits(ladder, shape))))
+
+
 # =================================================================== transports
 class Transport(abc.ABC):
     """How messages move between endpoints and where interchange math runs.
@@ -257,7 +281,6 @@ class Transport(abc.ABC):
         self.accountant = None
         if privacy is not None:
             if accountant is None:
-                from repro.comm.privacy import PrivacyAccountant
                 accountant = PrivacyAccountant()
             self.accountant = accountant
 
@@ -291,14 +314,51 @@ class Transport(abc.ABC):
     def bind(self, endpoints: Sequence["AgentEndpoint"]) -> None:
         self._endpoints = {ep.name: ep for ep in endpoints}
 
-    def send(self, msg: Message) -> None:
-        self._on_send(msg)
+    def send(self, msg: Message, rung: int | None = None) -> None:
+        """Deliver ``msg``; ``rung`` is the budget-ladder rung that priced
+        it (:meth:`book_hop` passes it), stamped on its ledger entry."""
+        self._on_send(msg, rung)
         ep = self._endpoints.get(msg.dst)
         if ep is not None:
             ep.receive(msg)
 
-    def _on_send(self, msg: Message) -> None:  # metering hook
-        pass
+    def _on_send(self, msg: Message, rung: int | None) -> None:
+        pass                                    # metering hook
+
+    # ---- the wire ledger ----------------------------------------------------
+    # Every hop either backend books goes through these three methods: the
+    # eager hop methods below call them from their tails, and the compiled
+    # backend's replays (Protocol._replay_traffic / _replay_serve, the
+    # FedAvg lowering's _replay) call them once per hop of the result arrays,
+    # priced by repro.comm.rung_bits.
+
+    def book_hop(self, msg: Message, *, spend=None, alpha=None) -> None:
+        """Book one shipped hop: ``msg`` is its payload message, priced
+        (``wire_bits`` set, or None for a raw payload).  In order: the
+        budget spend ``(rung, cost)`` on a budgeted transport; the payload,
+        its ledger entry stamped with that rung; the ``ModelWeightMsg`` when
+        ``alpha`` is given; the DP release of the sender when privacy is
+        on."""
+        rung = None
+        if spend is not None:
+            rung, cost = spend
+            self.record_spend((msg.src, msg.dst), cost, rung)
+        self.send(msg, rung)
+        if alpha is not None:
+            self.send(ModelWeightMsg(msg.src, msg.dst, float(alpha)))
+        if self.privacy is not None:
+            self.accountant.record(msg.src)
+
+    def skip_hop(self, link) -> None:
+        """Book one hop a budget dropped on ``link`` = (src, dst); only a
+        budgeted transport drops hops, so this books nothing here."""
+
+    def book_setup(self, head: str, dsts: Sequence[str], n: int) -> None:
+        """Collation setup: the head agent shares the labels and sample IDs
+        of ``n`` rows with each of ``dsts`` (metered under Fig. 4)."""
+        for dst in dsts:
+            self.send(LabelsMsg(head, dst, n))
+            self.send(SampleIdsMsg(head, dst, n))
 
     def _execute_update(self, w: jnp.ndarray, r: jnp.ndarray, alpha,
                         reweight: Callable, standard: bool) -> jnp.ndarray:
@@ -327,7 +387,7 @@ class Transport(abc.ABC):
     def interchange(self, src: "AgentEndpoint", dst: "AgentEndpoint",
                     w: jnp.ndarray, r: jnp.ndarray, alpha,
                     reweight: Callable, standard: bool = True, *,
-                    key=None, codec_state=None, _w_out=None):
+                    key=None, codec_state=None, _w_out=None, _spend=None):
         """One hop: w' = reweight(w, r, alpha), through the wire channel
         (DP noise, then codec encode/decode), shipped src -> dst.
 
@@ -338,30 +398,28 @@ class Transport(abc.ABC):
         from it, so attaching a channel never shifts the fit PRNG stream.
         ``_w_out`` lets a subclass that already ran the update (the
         budgeted transport's controller floor) pass it through instead of
-        recomputing it.
+        recomputing it; ``_spend`` is the ``(rung, cost)`` a budgeted
+        transport's ladder walk chose, booked by :meth:`book_hop`.
         """
         w_next = (_w_out if _w_out is not None
                   else self._execute_update(w, r, alpha, reweight, standard))
         self._choose_codec(w, w_next)
         wire_bits = None
         if self.has_channel:
-            from repro.comm.codecs import jitted_channel
             if (self.codec is not None and self.codec.stateful
                     and codec_state is None):
                 codec_state = self.codec.init_state(int(w.shape[0]))
             w_next, codec_state = jitted_channel(self.codec, self.privacy)(
                 w_next, key, codec_state)
-            if self.privacy is not None:
-                self.accountant.record(src.name)
             if self.codec is not None:
                 wire_bits = self.codec.wire_bits(int(w.shape[0]))
-        self.send(IgnoranceMsg(src.name, dst.name, w_next,
-                               wire_bits=wire_bits))
-        self.send(ModelWeightMsg(src.name, dst.name, float(alpha)))
+        self.book_hop(IgnoranceMsg(src.name, dst.name, w_next,
+                                   wire_bits=wire_bits),
+                      spend=_spend, alpha=alpha)
         return w_next, codec_state
 
     def serve_block(self, src: "AgentEndpoint", dst: "AgentEndpoint",
-                    block: jnp.ndarray, *, key=None):
+                    block: jnp.ndarray, *, key=None, _spend=None):
         """One prediction-time hop: ship ``src``'s [n, K] score block to
         ``dst`` (the head agent) through the serve channel — DP noise, then
         codec encode/decode — priced at its *encoded* size.
@@ -383,18 +441,15 @@ class Transport(abc.ABC):
             codec = self.serve_controller.ladder[rung]
         wire_bits = None
         if codec is not None or self.privacy is not None:
-            from repro.comm.codecs import jitted_channel
             block, _ = jitted_channel(codec, self.privacy)(block, key, None)
-            if self.privacy is not None:
-                self.accountant.record(src.name)
             if codec is not None:
                 wire_bits = int(codec.wire_bits(tuple(block.shape)))
-        self.send(ScoreBlockMsg(src.name, dst.name, block,
-                                wire_bits=wire_bits))
+        self.book_hop(ScoreBlockMsg(src.name, dst.name, block,
+                                    wire_bits=wire_bits), spend=_spend)
         return block
 
     def ship(self, src: "AgentEndpoint", dst: "AgentEndpoint",
-             payload: jnp.ndarray, wrap, *, key=None):
+             payload: jnp.ndarray, wrap, *, key=None, _spend=None):
         """One generic protocol-variant hop: ship ``payload`` (a FedAvg
         model delta, an Assisted-Learning residual block, ...) src -> dst
         through the wire channel — DP noise, then codec encode/decode —
@@ -411,18 +466,16 @@ class Transport(abc.ABC):
         """
         wire_bits = None
         if self.has_channel:
-            from repro.comm.codecs import jitted_channel
             payload, _ = jitted_channel(self.codec, self.privacy)(
                 payload, key, None)
-            if self.privacy is not None:
-                self.accountant.record(src.name)
             if self.codec is not None:
                 wire_bits = int(self.codec.wire_bits(tuple(payload.shape)))
-        self.send(wrap(src.name, dst.name, payload, wire_bits=wire_bits))
+        self.book_hop(wrap(src.name, dst.name, payload, wire_bits=wire_bits),
+                      spend=_spend)
         return payload
 
     def barrier_release(self, head: "AgentEndpoint", w_bar: jnp.ndarray, *,
-                        key=None, codec_state=None):
+                        key=None, codec_state=None, _spend=None):
         """One asynchronous-barrier release: the merged, renormalized score
         crosses the wire channel *once per round* — DP noise, then codec
         encode/decode, priced at its encoded size — published to the round
@@ -438,18 +491,15 @@ class Transport(abc.ABC):
         shifts the fit PRNG stream); ``codec_state`` is the barrier link's
         error-feedback residual for stateful codecs.
         """
-        from repro.comm.codecs import jitted_channel
         if (self.codec is not None and self.codec.stateful
                 and codec_state is None):
             codec_state = self.codec.init_state(int(w_bar.shape[0]))
         w_rel, codec_state = jitted_channel(self.codec, self.privacy)(
             w_bar, key, codec_state)
-        if self.privacy is not None:
-            self.accountant.record("barrier")
         wire_bits = (self.codec.wire_bits(int(w_bar.shape[0]))
                      if self.codec is not None else None)
-        self.send(IgnoranceMsg("barrier", head.name, w_rel,
-                               wire_bits=wire_bits))
+        self.book_hop(IgnoranceMsg("barrier", head.name, w_rel,
+                                   wire_bits=wire_bits), spend=_spend)
         return w_rel, codec_state
 
 
@@ -472,21 +522,10 @@ class MeteredTransport(Transport):
                          serve_controller=serve_controller)
         self.log = log if log is not None else TransportLog()
 
-    def _on_send(self, msg: Message) -> None:
-        if msg.wire_bits is not None:
-            # a budgeted subclass arms _pending_rung in record_spend; the
-            # wire-priced booking that follows consumes it, stamping the
-            # chosen ladder rung onto the ledger entry so a registry
-            # attached *after* the traffic can still backfill
-            # hops_by_rung_total
-            rung = getattr(self, "_pending_rung", None)
-            self.log.send_bits(msg.src, msg.dst, msg.kind, msg.wire_bits,
-                               rung=rung)
-            if rung is not None:
-                self._pending_rung = None
-        else:
-            self.log.send(msg.src, msg.dst, msg.kind, msg.num_elements,
-                          msg.bits_per_element)
+    def _on_send(self, msg: Message, rung: int | None) -> None:
+        # the budget rung rides the ledger entry, so a registry attached
+        # *after* the traffic can still backfill hops_by_rung_total
+        self.log.send_bits(msg.src, msg.dst, msg.kind, msg.bits, rung=rung)
 
     @property
     def total_bits(self) -> int:
@@ -1037,7 +1076,7 @@ class Session:
             self._live = telemetry.live
             self._live_prev = self._live_counters()
         if _send_setup:
-            self._send_setup()
+            self._send_setup_to(self.endpoints[1:])
 
     # ---- live emission ------------------------------------------------------
     def _live_counters(self) -> tuple:
@@ -1067,17 +1106,11 @@ class Session:
             return nullcontext()
         return self.telemetry.span(name, step, **attrs)
 
-    def _send_setup_to(self, ep: AgentEndpoint) -> None:
-        """Collation setup for one endpoint: the head agent shares labels
-        and sample IDs (metered under Fig. 4)."""
-        n = int(self.classes.shape[0])
-        head = self.endpoints[0].name
-        self.transport.send(LabelsMsg(head, ep.name, n))
-        self.transport.send(SampleIdsMsg(head, ep.name, n))
-
-    def _send_setup(self) -> None:
-        for ep in self.endpoints[1:]:
-            self._send_setup_to(ep)
+    def _send_setup_to(self, eps: Sequence[AgentEndpoint]) -> None:
+        """Collation setup for ``eps`` (:meth:`Transport.book_setup`)."""
+        self.transport.book_setup(self.endpoints[0].name,
+                                  [ep.name for ep in eps],
+                                  int(self.classes.shape[0]))
 
     def add_endpoint(self, learner: Learner, X: jnp.ndarray,
                      name: str = "") -> AgentEndpoint:
@@ -1086,7 +1119,7 @@ class Session:
         ep = AgentEndpoint(len(self.endpoints), learner, X, name=name)
         self.endpoints.append(ep)
         self.transport.bind(self.endpoints)
-        self._send_setup_to(ep)
+        self._send_setup_to([ep])
         return ep
 
     def _reweight(self):
@@ -1287,7 +1320,6 @@ class Session:
                 f"session.fitted().predict(Xs)")
         head = self.endpoints[0]
         if key is None and self.transport.has_serve_channel:
-            from repro.comm.codecs import serve_key
             key = serve_key(self.state.key, request)
         if self._live is not None:
             reg = self.telemetry.registry
@@ -1514,7 +1546,6 @@ class Protocol:
         ``tokens_predict`` and ``expert_tokens`` (split as
         ``expert_tokens_fit`` and ``expert_tokens_predict``), the tokens
         routed to held experts."""
-        from repro.core import compiled
         if not isinstance(self.variant, ASCIIVariant):
             self._attach_telemetry()
             # protocol variants own their lowering (repro.scenarios.compiled
@@ -1564,11 +1595,11 @@ class Protocol:
                     dispatches += (result.alphas.shape[0] + 1) * stacked
                 span.attrs.update(components=len(fitted.components),
                                   leaves=leaves, dispatches=dispatches)
-        replay = self._replay_traffic_async if stale else self._replay_traffic
         log = getattr(self.transport, "log", None)
         with self._span("replay", backend="compiled") as span:
             booked = 0 if log is None else len(log.entries)
-            dispatches = replay(endpoints, classes, result, plan)
+            dispatches = self._replay_traffic(endpoints, classes, result,
+                                              plan)
             if span is not None:
                 span.attrs["dispatches"] = dispatches
                 if log is not None:
@@ -1595,7 +1626,6 @@ class Protocol:
         """The :class:`repro.core.compiled.SessionPlan` of an ASCII run on
         this protocol's config, transport and scheduler; raises for what
         the compiled backend does not lower."""
-        from repro.core import compiled
         cfg = self.cfg
         if self.scenario is not None and not self.scenario.trivial:
             raise ValueError(
@@ -1647,147 +1677,86 @@ class Protocol:
             scheduler=sched_plan)
 
     def _replay_traffic(self, endpoints: Sequence[AgentEndpoint],
-                        classes: jnp.ndarray, result, plan=None) -> int:
-        """Book the message ledger a sequential eager run would have
-        produced: collation setup, then one IgnoranceMsg + ModelWeightMsg
-        per component-producing hop, in chain order — at the *encoded* size
-        of whichever codec rung the scan shipped each hop with, skipping
-        budget-dropped hops, and tallying the privacy accountant, so the
-        compiled ledger is byte-identical to the eager one.
+                        classes: jnp.ndarray, result, plan) -> int:
+        """Book the message ledger the eager run would have produced, from
+        the compiled result arrays: the collation setup, then every hop
+        through :meth:`Transport.book_hop` (a budget-dropped one through
+        ``skip_hop``) at the price of the rung the program shipped it at,
+        then the exhaustion flag — byte-identical to the eager ledger.
 
-        Each IgnoranceMsg carries row ``[t, j]`` of ``result.w_trace``, all
-        rows cut by one launch of :func:`repro.core.compiled.split_rows`
-        (an eager slice per hop costs a host dispatch each).  Returns the
-        device programs launched: 1."""
-        from repro.core import compiled
-        self.transport.bind(endpoints)
+        Sequential: one IgnoranceMsg + alpha per component-producing hop, in
+        chain order, carrying row ``w_trace[t, j]``.  Async-stale without a
+        channel: the per-agent mid-merge pairs, carrying ``w_trace[t, m]``;
+        with one: the raw per-agent alphas to the barrier, then the round's
+        one release of ``w_bar[t]``.  The payload rows come from one launch
+        of :func:`repro.core.compiled.split_rows` (an eager slice per hop
+        costs a host dispatch each).  Returns the device programs launched:
+        1."""
+        tr = self.transport
+        tr.bind(endpoints)
         n = int(classes.shape[0])
-        head = endpoints[0].name
-        for ep in endpoints[1:]:
-            self.transport.send(LabelsMsg(head, ep.name, n))
-            self.transport.send(SampleIdsMsg(head, ep.name, n))
-        valid = np.asarray(result.valid)
-        alphas = np.asarray(result.alphas)
-        accs = np.asarray(result.accs)
-        executed = np.asarray(result.executed)
-        sent = np.asarray(result.sent)
-        codec_idx = np.asarray(result.codec_idx)
-        order = getattr(result, "order", None)
-        order = None if order is None else np.asarray(order)
-        # launched after the reads above wait for the session, so the copies
-        # never sit beside its working memory
-        w_rows = compiled.split_rows(result.w_trace)
-        ladder = plan.ladder if plan is not None and plan.has_channel else None
-        budget = plan.budget if plan is not None else None
-        budgeted = budget is not None and hasattr(self.transport,
-                                                  "link_spent")
-        # a permuting scheduler replays too: round_order reads the live
-        # ledger state at each round entry (telemetry + RNG side effects)
-        # and observe feeds the reward EMAs — so post-run scheduler state
-        # and registry counters match the eager session's exactly
-        permuted = plan is not None and plan.scheduler is not None
-        num = len(endpoints)
-        for t in range(valid.shape[0]):
-            if permuted and executed[t].any():
-                self.scheduler.round_order(t, list(range(num)))
-            for j in range(num):
-                src = j if order is None else int(order[t, j])
-                dst_i = ((j + 1) % num if order is None
-                         else int(order[t, (j + 1) % num]))
-                if permuted and executed[t, j]:
-                    self.scheduler.observe(src, float(accs[t, j]))
-                if not valid[t, j]:
-                    continue
-                dst = endpoints[dst_i]
-                link = (endpoints[src].name, dst.name)
-                if not sent[t, j]:
-                    if budgeted:
-                        self.transport.record_skip(link)
-                    continue
-                if budgeted:
-                    # spend-first, like the eager ladder walk: record_spend
-                    # arms the rung stamp the wire-priced send consumes
-                    rung = int(codec_idx[t, j])
-                    self.transport.record_spend(
-                        link, budget.hop_costs(n)[rung], rung)
-                codec = ladder[int(codec_idx[t, j])] if ladder else None
-                wire_bits = codec.wire_bits(n) if codec is not None else None
-                self.transport.send(IgnoranceMsg(
-                    endpoints[src].name, dst.name, w_rows[t][j],
-                    wire_bits=wire_bits))
-                self.transport.send(ModelWeightMsg(
-                    endpoints[src].name, dst.name, float(alphas[t, j])))
-                if self.transport.privacy is not None:
-                    self.transport.accountant.record(endpoints[src].name)
-        if budgeted:
-            self.transport.exhausted = bool(result.exhausted)
-        return 1
-
-    def _replay_traffic_async(self, endpoints: Sequence[AgentEndpoint],
-                              classes: jnp.ndarray, result, plan) -> int:
-        """Book the ledger an eager async-stale run produces: channel-less,
-        the per-agent mid-merge IgnoranceMsg + ModelWeightMsg pairs; with a
-        wire channel, the raw per-agent alpha messages followed by the one
-        per-barrier release (or its budget skip) — spend-first, rung
-        stamped, DP release tallied, byte-identical to the eager barrier.
-
-        The payloads (``w_trace[t, m]`` channel-less, else ``w_bar[t]``)
-        come from one launch of :func:`repro.core.compiled.split_rows`.
-        Returns the device programs launched: 1."""
-        from repro.core import compiled
-        self.transport.bind(endpoints)
-        n = int(classes.shape[0])
-        head = endpoints[0].name
-        for ep in endpoints[1:]:
-            self.transport.send(LabelsMsg(head, ep.name, n))
-            self.transport.send(SampleIdsMsg(head, ep.name, n))
+        names = [ep.name for ep in endpoints]
+        num = len(names)
+        tr.book_setup(names[0], names[1:], n)
+        stale = isinstance(result, compiled.AsyncSessionResult)
+        barrier = stale and plan.has_channel
         executed = np.asarray(result.executed)
         valid = np.asarray(result.valid)
         alphas = np.asarray(result.alphas)
         sent = np.asarray(result.sent)
         rungs = np.asarray(result.codec_idx)
-        num = len(endpoints)
-        channel = plan.has_channel
-        rows = compiled.split_rows(result.w_bar if channel
+        # the async flags are per round (the barrier's); its channel-less
+        # per-agent hops all ship, raw
+        hop_sent, hop_rungs = ((valid, np.zeros(valid.shape, int)) if stale
+                               else (sent, rungs))
+        order = getattr(result, "order", None)
+        order = None if order is None else np.asarray(order)
+        # launched after the reads above wait for the session, so the copies
+        # never sit beside its working memory
+        rows = compiled.split_rows(result.w_bar if barrier
                                    else result.w_trace)
-        budget = plan.budget
-        budgeted = budget is not None and hasattr(self.transport,
-                                                  "link_spent")
+        # prices per rung, once per replay (a channel-less plan's ladder is
+        # the one raw rung)
+        budgeted = plan.budget is not None
+        prices = hop_prices(plan.ladder, n, budgeted=budgeted,
+                            extra=0 if barrier else MODEL_WEIGHT_BITS)
+        # a permuting scheduler replays too: round_order reads the live
+        # ledger state at each round entry (telemetry + RNG side effects)
+        # and observe feeds the reward EMAs — so post-run scheduler state
+        # and registry counters match the eager session's exactly
+        permuted = not stale and plan.scheduler is not None
+        accs = np.asarray(result.accs) if permuted else None
         for t in range(valid.shape[0]):
-            if not executed[t].any():
-                break
-            if not channel:
-                for m in range(num):
-                    if not valid[t, m]:
-                        continue
-                    dst = endpoints[(m + 1) % num]
-                    self.transport.send(IgnoranceMsg(
-                        endpoints[m].name, dst.name, rows[t][m]))
-                    self.transport.send(ModelWeightMsg(
-                        endpoints[m].name, dst.name, float(alphas[t, m])))
+            if permuted and executed[t].any():
+                self.scheduler.round_order(t, list(range(num)))
+            for j in range(num):
+                src = j if order is None else int(order[t, j])
+                dst = ((j + 1) % num if order is None
+                       else int(order[t, (j + 1) % num]))
+                if permuted and executed[t, j]:
+                    self.scheduler.observe(src, float(accs[t, j]))
+                if not valid[t, j]:
+                    continue
+                if barrier:
+                    tr.send(ModelWeightMsg(names[src], "barrier",
+                                           float(alphas[t, j])))
+                elif not hop_sent[t, j]:
+                    tr.skip_hop((names[src], names[dst]))
+                else:
+                    wire, spend = prices[hop_rungs[t, j]]
+                    tr.book_hop(IgnoranceMsg(names[src], names[dst],
+                                             rows[t][j], wire_bits=wire),
+                                spend=spend, alpha=alphas[t, j])
+            if not (barrier and executed[t].any()):
                 continue
-            for m in range(num):
-                if valid[t, m]:
-                    self.transport.send(ModelWeightMsg(
-                        endpoints[m].name, "barrier", float(alphas[t, m])))
-            link = ("barrier", endpoints[0].name)
             if not sent[t]:
-                if budgeted:
-                    self.transport.record_skip(link)
+                tr.skip_hop(("barrier", names[0]))
                 continue
-            rung = int(rungs[t])
-            codec = plan.ladder[rung] if rung >= 0 else None
-            if budgeted:
-                self.transport.record_spend(
-                    link, budget.payload_costs(n)[rung], rung)
-            wire_bits = codec.wire_bits(n) if codec is not None else None
-            self.transport.send(IgnoranceMsg(
-                "barrier", endpoints[0].name, rows[t],
-                wire_bits=wire_bits))
-            if self.transport.privacy is not None:
-                self.transport.accountant.record("barrier")
+            wire, spend = prices[rungs[t]]
+            tr.book_hop(IgnoranceMsg("barrier", names[0], rows[t],
+                                     wire_bits=wire), spend=spend)
         if budgeted:
-            self.transport.exhausted = bool(result.exhausted)
+            tr.exhausted = bool(result.exhausted)
         return 1
 
     # ---- serve path ---------------------------------------------------------
@@ -1816,12 +1785,10 @@ class Protocol:
             # state.key, matching the compiled branch below
             return self._session.predict_distributed(Xs, max_round, key=key,
                                                      request=request)
-        from repro.core import compiled
         if self._compiled_ctx is None:
             raise RuntimeError("predict_distributed needs a completed fit()")
         endpoints, plan, result = self._compiled_ctx
         if key is None and self.transport.has_serve_channel:
-            from repro.comm.codecs import serve_key
             key = serve_key(self._evolved_key(result), request)
         Xs_serve = (tuple(ep.X for ep in endpoints) if Xs is None
                     else tuple(jnp.asarray(x) for x in Xs))
@@ -1830,7 +1797,7 @@ class Protocol:
             mask = (jnp.arange(valid.shape[0]) <= max_round)[:, None]
             valid = jnp.logical_and(valid, mask)
         shape = (int(Xs_serve[0].shape[0]), self.cfg.num_classes)
-        rem_session, rem_link = self._serve_remaining(endpoints, shape, plan)
+        rem_session, rem_link = self._serve_remaining(endpoints, plan)
         live_sink = self._live_sink()
         with self._span("serve", backend="compiled",
                         agents=len(endpoints)):
@@ -1852,7 +1819,6 @@ class Protocol:
         same split count lands on the identical key."""
         executed = np.asarray(result.executed)
         splits = int(executed.sum())
-        from repro.core import compiled
         if isinstance(result, compiled.AsyncSessionResult) \
                 and self.transport.has_channel:
             splits += int(executed.any(axis=1).sum())
@@ -1861,57 +1827,41 @@ class Protocol:
             k, _ = jax.random.split(k)
         return k
 
-    def _serve_remaining(self, endpoints, shape, plan):
-        """Host-side remaining-budget snapshot the traced serve step starts
-        from (the compiled analogue of BudgetedTransport's per-hop reads)."""
-        num = len(endpoints)
-        if plan.budget is None or not hasattr(self.transport, "link_spent"):
-            return None, None
-        t, budget = self.transport, plan.budget
-        rem_s = (np.iinfo(np.int32).max if budget.session_bits is None
-                 else budget.session_bits - t.log.total_bits
-                 - t.carryover_bits)
+    def _serve_remaining(self, endpoints, plan) -> tuple:
+        """The remaining-budget counters the traced serve step starts from
+        (the compiled analogue of the budgeted transport's per-hop reads):
+        session bits, and each agent's link bits to the head, clamped to
+        int32 (uncapped: its max)."""
+        top = int(np.iinfo(np.int32).max)
+        if plan.budget is None or not hasattr(self.transport, "remaining"):
+            return top, (top,) * len(endpoints)
         head = endpoints[0].name
-        rem_l = []
-        for ep in endpoints:
-            link = (ep.name, head)
-            rem_l.append(np.iinfo(np.int32).max if budget.link_bits is None
-                         else budget.link_bits - t.link_spent.get(link, 0))
-        return int(rem_s), tuple(int(r) for r in rem_l)
+        rem = [self.transport.remaining((ep.name, head)) for ep in endpoints]
+        return (min(rem[0][0], top),
+                tuple(min(rem_l, top) for _, rem_l in rem))
 
     def _replay_serve(self, endpoints, serve, shape, plan) -> None:
-        """Book the serve-path message ledger the eager path would have
-        produced: one ScoreBlockMsg per shipped block at the encoded size of
-        the rung the traced serve step chose, skipped links recorded, DP
-        releases tallied, budget state advanced — byte-identical to eager
-        ``Session.predict_distributed``."""
-        head = endpoints[0]
+        """Book the serve-path ledger the eager path would have produced:
+        one ScoreBlockMsg per shipped block through
+        :meth:`Transport.book_hop`, at the price of the rung the traced
+        serve step chose; skipped links through ``skip_hop`` —
+        byte-identical to eager ``Session.predict_distributed``."""
+        tr = self.transport
+        names = [ep.name for ep in endpoints]
         sent = np.asarray(serve.sent)
         rungs = np.asarray(serve.codec_idx)
-        ladder = plan.serve_ladder
-        budgeted = (plan.budget is not None
-                    and hasattr(self.transport, "link_spent"))
-        for j in range(1, len(endpoints)):
-            link = (endpoints[j].name, head.name)
+        prices = hop_prices(plan.serve_ladder, shape,
+                            budgeted=plan.budget is not None)
+        for j in range(1, len(names)):
             if not sent[j]:
-                if budgeted:
-                    self.transport.record_skip(link)
+                tr.skip_hop((names[j], names[0]))
                 continue
-            codec = ladder[int(rungs[j])] if int(rungs[j]) >= 0 else None
-            wire_bits = (int(codec.wire_bits(shape))
-                         if codec is not None else None)
-            if budgeted:
-                # spend-first, like the eager ladder walk: record_spend arms
-                # _pending_rung so the booking below stamps the rung
-                self.transport.record_spend(link, wire_bits, int(rungs[j]))
-            self.transport.send(ScoreBlockMsg(
-                endpoints[j].name, head.name, serve.blocks[j],
-                wire_bits=wire_bits))
-            if self.transport.privacy is not None:
-                self.transport.accountant.record(endpoints[j].name)
-        if budgeted:
-            self.transport.exhausted = bool(self.transport.exhausted
-                                            or bool(serve.exhausted))
+            # a block shipped at rung -1 rode the one raw serve rung
+            wire, spend = prices[max(int(rungs[j]), 0)]
+            tr.book_hop(ScoreBlockMsg(names[j], names[0], serve.blocks[j],
+                                      wire_bits=wire), spend=spend)
+        if plan.budget is not None:
+            tr.exhausted = bool(tr.exhausted or bool(serve.exhausted))
 
 
 def variant_setup(variant: str, seed: int = 0) -> tuple[Scheduler, bool]:

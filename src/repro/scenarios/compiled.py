@@ -26,13 +26,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compiled import _INT32_MAX, ladder_walk, rung_select
+from repro.comm.codecs import rung_bits
+from repro.core.compiled import (_INT32_MAX, check_caps, ladder_walk,
+                                 rung_price, rung_select)
+from repro.core.engine import setup_bits
 from repro.scenarios.protocols import (fedavg_combine, fedavg_init_flat,
                                        fedavg_local_delta,
                                        fedavg_fit_weights, _param_template)
-
-#: A raw fp32 broadcast element (the downlink GradientMsg is never encoded).
-_RAW_BITS = 32
 
 
 @dataclass(frozen=True)
@@ -92,12 +92,11 @@ def make_fedavg_fn(plan: FedAvgPlan, feature_shape: tuple):
     ladder = plan.ladder
     has_channel = plan.has_channel
     d, _ = _param_template(core, tuple(feature_shape))
+    # one uplink's bits per rung: what the replay books for its GradientMsg
+    uplink_bits = rung_bits(ladder, (d,))
     if budget is not None:
-        for cap in (budget.session_bits, budget.link_bits):
-            if cap is not None and cap >= _INT32_MAX:
-                raise ValueError(f"budget caps must fit int32 (the scan's "
-                                 f"spent-bit counters), got {cap}")
-        if max(budget.payload_costs((d,))) >= _INT32_MAX:
+        check_caps(budget)
+        if max(uplink_bits) >= _INT32_MAX:
             raise ValueError("uplink payload costs must fit int32")
 
     def fedavg_fn(key: jax.Array, Xs: tuple, classes: jnp.ndarray,
@@ -108,13 +107,10 @@ def make_fedavg_fn(plan: FedAvgPlan, feature_shape: tuple):
         onehot = jax.nn.one_hot(classes, k)
         g0 = fedavg_init_flat(core, feature_shape, key)
         if budget is not None:
-            costs = tuple(jnp.asarray(c, jnp.int32)
-                          for c in budget.payload_costs((d,)))
-            min_cost = min(budget.payload_costs((d,)))
-            from repro.core.engine import LabelsMsg, SampleIdsMsg
-            setup_bits = (num - 1) * (LabelsMsg("", "", n).bits
-                                      + SampleIdsMsg("", "", n).bits)
-        bcast_bits = d * _RAW_BITS
+            costs = tuple(jnp.asarray(c, jnp.int32) for c in uplink_bits)
+            min_cost = min(uplink_bits)
+        # the server's raw fp32 broadcast of the new global model
+        bcast_bits = rung_bits((None,), (d,))[0]
 
         def round_body(carry, mask_t):
             key, g, stopped = carry["key"], carry["g"], carry["stopped"]
@@ -172,10 +168,7 @@ def make_fedavg_fn(plan: FedAvgPlan, feature_shape: tuple):
                 sent_l.append(sent)
                 rung_l.append(jnp.where(sent, rung, -1))
                 if budget is not None:
-                    cost = jnp.select(
-                        [rung == i for i in range(len(ladder))],
-                        list(costs), jnp.asarray(0, jnp.int32))
-                    add = jnp.where(sent, cost, 0)
+                    add = jnp.where(sent, rung_price(rung, costs), 0)
                     carry["spent"] = carry["spent"] + add
                     carry["link"] = carry["link"].at[j].add(add)
                     if budget.session_bits is not None:
@@ -207,7 +200,7 @@ def make_fedavg_fn(plan: FedAvgPlan, feature_shape: tuple):
 
         init = {"key": key, "g": g0, "stopped": jnp.zeros((), bool)}
         if budget is not None:
-            init["spent"] = jnp.asarray(setup_bits, jnp.int32)
+            init["spent"] = jnp.asarray(setup_bits(num, n), jnp.int32)
             init["link"] = jnp.zeros((num,), jnp.int32)
             init["exhausted"] = jnp.zeros((), bool)
         fin, ys = jax.lax.scan(round_body, init,

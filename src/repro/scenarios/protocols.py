@@ -42,7 +42,7 @@ from jax.flatten_util import ravel_pytree
 
 from repro.core.engine import (ASCIIVariant, Component, GradientMsg,
                                ProtocolVariant, ResidualMsg,
-                               SequentialScheduler)
+                               SequentialScheduler, hop_prices)
 
 #: fold_in tag deriving FedAvg's global-init key off the session key, so
 #: model init never consumes PRNG state the per-round splits would see
@@ -354,59 +354,39 @@ class FedAvgVariant(ProtocolVariant):
     @staticmethod
     def _replay(protocol, endpoints, classes, result, plan, mask) -> None:
         """Book the eager run's exact message ledger: collation setup, one
-        GradientMsg uplink per sent (round, client) at the rung the scan
-        chose, skipped links, DP releases, link spend, the raw broadcast per
-        participating client — then the exhaustion flag."""
-        from repro.core.engine import LabelsMsg, SampleIdsMsg
+        GradientMsg uplink per sent (round, client) through
+        ``Transport.book_hop`` at the rung the scan chose (a skipped one
+        through ``skip_hop``), the raw broadcast per participating client —
+        then the exhaustion flag."""
         transport = protocol.transport
         transport.bind(endpoints)
-        n = int(classes.shape[0])
-        head = endpoints[0]
-        for ep in endpoints[1:]:
-            transport.send(LabelsMsg(head.name, ep.name, n))
-            transport.send(SampleIdsMsg(head.name, ep.name, n))
+        names = [ep.name for ep in endpoints]
+        transport.book_setup(names[0], names[1:], int(classes.shape[0]))
         d, _ = _param_template(plan.core, tuple(endpoints[0].X.shape[1:]))
         flat = np.zeros((d,), np.float32)  # ledger prices size, not values
         executed = np.asarray(result.executed)
         sent = np.asarray(result.sent)
         rungs = np.asarray(result.codec_idx)
-        budget = plan.budget
-        budgeted = budget is not None and hasattr(transport, "link_spent")
-        costs = (None if budget is None
-                 else budget.payload_costs((d,)))
+        # prices per rung, once per replay (the scan ships a budget-less
+        # uplink at rung 0: the plan's codec, or raw)
+        prices = hop_prices(plan.ladder, (d,),
+                            budgeted=plan.budget is not None)
         for t in range(executed.shape[0]):
             if not executed[t]:
                 continue
-            for j in range(1, len(endpoints)):
+            for j in range(1, len(names)):
                 if not mask[t, j]:
                     continue
-                link = (endpoints[j].name, head.name)
                 if not sent[t, j]:
-                    if budgeted:
-                        transport.record_skip(link)
+                    transport.skip_hop((names[j], names[0]))
                     continue
-                codec = None
-                if budget is not None:
-                    codec = budget.ladder[int(rungs[t, j])]
-                elif plan.codec is not None:
-                    codec = plan.codec
-                wire_bits = (int(codec.wire_bits((d,)))
-                             if codec is not None else None)
-                if budgeted:
-                    # spend-first, like the eager ladder walk: record_spend
-                    # arms _pending_rung so the booking below stamps the
-                    # chosen rung onto the ledger entry
-                    rung = int(rungs[t, j])
-                    transport.record_spend(link, costs[rung], rung)
-                transport.send(GradientMsg(endpoints[j].name, head.name,
-                                           flat, wire_bits=wire_bits))
-                if transport.privacy is not None:
-                    transport.accountant.record(endpoints[j].name)
-            for j in range(1, len(endpoints)):
+                wire, spend = prices[rungs[t, j]]
+                transport.book_hop(GradientMsg(names[j], names[0], flat,
+                                               wire_bits=wire), spend=spend)
+            for j in range(1, len(names)):
                 if mask[t, j]:
-                    transport.send(GradientMsg(head.name, endpoints[j].name,
-                                               flat))
-        if budgeted:
+                    transport.send(GradientMsg(names[0], names[j], flat))
+        if plan.budget is not None:
             transport.exhausted = bool(result.exhausted)
 
 

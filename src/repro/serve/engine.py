@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.comm.codecs import rung_bits
 from repro.comm.privacy import PrivacyAccountant
 from repro.serve.admission import DENY, AdmissionController, Decision
 from repro.serve.batcher import Batcher, Slot
@@ -146,20 +147,7 @@ class ServeEngine:
                 "(the serve engine batches traced serve programs)")
         endpoints, plan, result = ctx
         evolved = protocol._evolved_key(result)
-        num = plan.num_agents
-        rem_s, rem_l = _INT32_MAX, [_INT32_MAX] * num
-        budget = plan.budget
-        if budget is not None and hasattr(protocol.transport, "link_spent"):
-            t = protocol.transport
-            if budget.session_bits is not None:
-                rem_s = min(budget.session_bits - t.log.total_bits
-                            - t.carryover_bits, _INT32_MAX)
-            if budget.link_bits is not None:
-                head = endpoints[0].name
-                rem_l = [min(budget.link_bits
-                             - t.link_spent.get((ep.name, head), 0),
-                             _INT32_MAX)
-                         for ep in endpoints]
+        rem_s, rem_l = protocol._serve_remaining(endpoints, plan)
         state = ServeSessionState(
             params=result.params, alphas=result.alphas, valid=result.valid,
             key_data=jax.random.key_data(evolved),
@@ -173,9 +161,7 @@ class ServeEngine:
     def _min_full_bits(self, meta: SessionMeta, shape: tuple) -> int:
         """Cheapest-rung full-serve wire cost: the coarsest serve-ladder
         price for every non-head block (raw fp32 when the rung is None)."""
-        raw = 32 * shape[0] * shape[1]
-        cheapest = min((int(c.wire_bits(shape)) if c is not None else raw)
-                       for c in meta.plan.serve_ladder)
+        cheapest = min(rung_bits(meta.plan.serve_ladder, shape))
         return cheapest * (len(meta.names) - 1)
 
     # ---------------------------------------------------------------- submit
@@ -229,9 +215,11 @@ class ServeEngine:
     # ----------------------------------------------------------------- flush
     def _book(self, slot: Slot, res) -> ServeOutcome:
         """Settle one served slot: replay the per-request serve ledger the
-        standalone path books (``Protocol._replay_serve``), under
-        session-prefixed endpoint names so sessions never collide in the
-        fleet-wide log, then charge the tenant the same bits."""
+        standalone path books (``Protocol._replay_serve``), priced by the
+        same ``rung_bits`` table, under session-prefixed endpoint names so
+        sessions never collide in the fleet-wide log (hence its own loop,
+        not ``Transport.book_hop``), then charge the tenant the same
+        bits."""
         from repro.core.transport import TransportLog
         if self.log is None:
             self.log = TransportLog(registry=self.registry)
@@ -239,7 +227,7 @@ class ServeEngine:
         meta = self.sessions[sid]
         plan, names = meta.plan, meta.names
         shape = (int(slot.Xs[0].shape[0]), plan.num_classes)
-        ladder = plan.serve_ladder
+        costs = rung_bits(plan.serve_ladder, shape)
         sent = np.asarray(res.sent)
         rungs = np.asarray(res.codec_idx)
         deliver = np.asarray(slot.deliver)
@@ -257,9 +245,8 @@ class ServeEngine:
                                   src=link[0], dst=link[1])
                 continue
             rung = int(rungs[j])
-            codec = ladder[rung] if rung >= 0 else None
-            bits = (int(codec.wire_bits(shape)) if codec is not None
-                    else 32 * shape[0] * shape[1])
+            # a block shipped at rung -1 rode the one raw serve rung
+            bits = costs[max(rung, 0)]
             self.log.send_bits(link[0], link[1], "score_block", bits)
             bits_total += bits
             link_cost[j] = bits

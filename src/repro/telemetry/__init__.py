@@ -20,10 +20,10 @@ dispatches inside traced code, never perturbs the budget ladder walk.
 
 Emission sits at the choke points both engine backends share
 (`TransportLog.send_bits`, `PrivacyAccountant.record`,
-`BudgetedTransport.record_skip`/`record_spend`): eager paths emit live as
-hops happen; the compiled backend emits while `Protocol._replay_traffic` /
-`_replay_serve` / the scenario `_replay` walk the scanned ledger — so eager
-and compiled runs produce identical registries wherever their ledgers
+`BudgetedTransport.skip_hop`/`record_spend`), all reached through the one
+wire ledger `Transport.book_hop`/`skip_hop`: eager hops book as they
+happen, the compiled replays book hop by hop from the result arrays — so
+eager and compiled runs produce identical registries wherever their ledgers
 already agree (which the backend-parity tests pin).
 """
 from __future__ import annotations

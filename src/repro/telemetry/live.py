@@ -1,8 +1,9 @@
 """In-flight metric emission from compiled programs (and eager twins).
 
-The compiled backend books every metric *after* the run: `Protocol.
-_replay_traffic` walks the scanned ledger once the scan has returned, so a
-long `fleet_run` or `control_sweep_run` is a black box while it executes.
+The compiled backend books every metric *after* the run: its replay
+(`Protocol._replay_traffic`, one `Transport.book_hop` per hop) walks the
+scanned ledger once the scan has returned, so a long `fleet_run` or
+`control_sweep_run` is a black box while it executes.
 This module adds the live plane: tiny `jax.debug.callback` taps inside the
 scanned round body (and the serve dispatch) stream per-round scalars to a
 host-side :class:`LiveSink` *while the program runs* — feeding the same
@@ -16,7 +17,8 @@ Zero-interference contract (pinned by tests/test_telemetry_live.py and
     body already computes and feed them to `jax.debug.callback`, which has
     no data-flow back into the program; posteriors/ledgers are unchanged.
   * **final live registry == replay-booked registry** — the per-round
-    deltas are priced by the *same formulas* the replay walk uses, so at
+    deltas are priced by the *same table* the replay walk books
+    (`repro.comm.codecs.rung_bits`), so at
     program exit ``live_wire_bits_total == wire_bits_total``,
     ``live_messages_total{kind=ignorance} == messages_total{kind=
     ignorance}``, ``live_budget_skips_total == budget_skips_total``.

@@ -17,10 +17,11 @@ Design constraints (the telemetry hard invariant):
     bit-identical on every pinned trajectory.
   * **both backends, one layer** — emission hooks sit at the choke points
     both backends already share (`TransportLog.send_bits`,
-    `PrivacyAccountant.record`, `BudgetedTransport.record_skip`/
-    `record_spend`): eager paths emit live, the compiled backend emits
-    during its post-run ledger replay, so eager and compiled runs produce
-    identical registries wherever their ledgers already agree.
+    `PrivacyAccountant.record`, `BudgetedTransport.skip_hop`/
+    `record_spend`, all reached through `Transport.book_hop`/`skip_hop`):
+    eager paths emit live, the compiled backend emits during its post-run
+    ledger replay, so eager and compiled runs produce identical registries
+    wherever their ledgers already agree.
   * **cheap** — an increment is one dict update on a sorted-label key; no
     locks, no strings formatted until export.
 

@@ -2,6 +2,8 @@
 (hypothesis: quantize kernel vs host reference across dtypes and tilings),
 top-k error feedback, the Gaussian mechanism + accountant, budget specs,
 and the hardened TransportLog."""
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +13,8 @@ from repro.comm import (BudgetSpec, GaussianMechanism, PrivacyAccountant,
                         make_codec)
 from repro.comm.budget import MODEL_WEIGHT_BITS
 from repro.comm.codecs import (Fp16Codec, Fp32Codec, QuantCodec, TopKCodec,
-                               quant_bits_per_element)
+                               quant_bits_per_element, rung_bits)
+from repro.core.engine import GradientMsg, MeteredTransport
 from repro.core.transport import TransportLog
 from repro.kernels import ops, ref
 
@@ -362,13 +365,56 @@ def test_budget_choose_rule():
     costs = spec.hop_costs(n)
     assert costs[0] == 32 * n + MODEL_WEIGHT_BITS
     assert list(costs) == sorted(costs, reverse=True)   # ladder degrades
-    assert spec.choose(n, float("inf"), float("inf")) == 0
+    assert spec.choose_costs(costs, float("inf"), float("inf")) == 0
     # only the cheapest rung affordable
-    assert spec.choose(n, costs[-1], float("inf")) == len(costs) - 1
+    assert spec.choose_costs(costs, costs[-1], float("inf")) == \
+        len(costs) - 1
     # nothing affordable -> skip
-    assert spec.choose(n, costs[-1] - 1, float("inf")) is None
+    assert spec.choose_costs(costs, costs[-1] - 1, float("inf")) is None
     # the link cap binds too
-    assert spec.choose(n, float("inf"), costs[-1]) == len(costs) - 1
+    assert spec.choose_costs(costs, float("inf"), costs[-1]) == \
+        len(costs) - 1
+
+
+# =================================================== one price for every ledger
+PRICE_RUNGS = {"raw": None, "fp16": Fp16Codec(), "int8": QuantCodec(bits=8),
+               "int4": QuantCodec(bits=4)}
+N_PRICE, K_PRICE, D_PRICE = 70, 3, 33     # odd sizes: int4 pads a nibble
+_A, _B = SimpleNamespace(name="a"), SimpleNamespace(name="b")
+PRICE_HOPS = {
+    # an [n] ignorance hop with its 32-bit alpha
+    "ignorance": ((N_PRICE,), MODEL_WEIGHT_BITS,
+                  lambda t, x, key: t.interchange(_A, _B, x, x, 0.5,
+                                                  lambda w, r, a: w,
+                                                  key=key)),
+    # a bare [n] async-barrier release
+    "barrier": ((N_PRICE,), 0,
+                lambda t, x, key: t.barrier_release(_A, x, key=key)),
+    # an [n, K] prediction-time score block
+    "score_block": ((N_PRICE, K_PRICE), 0,
+                    lambda t, x, key: t.serve_block(_B, _A, x, key=key)),
+    # a [d] FedAvg gradient uplink
+    "gradient": ((D_PRICE,), 0,
+                 lambda t, x, key: t.ship(_B, _A, x, GradientMsg, key=key)),
+}
+
+
+@pytest.mark.parametrize("rung", sorted(PRICE_RUNGS))
+@pytest.mark.parametrize("hop", sorted(PRICE_HOPS))
+def test_rung_bits_is_what_book_hop_books(hop, rung):
+    """``rung_bits`` — the one price table of budget walks, compiled
+    replays and traced counters — equals the bits a metered transport books
+    for the messages ``book_hop`` sends on every eager hop kind, at every
+    rung, raw included."""
+    codec = PRICE_RUNGS[rung]
+    shape, extra, run = PRICE_HOPS[hop]
+    t = MeteredTransport(codec=codec)
+    x = jax.random.uniform(jax.random.key(3), shape)
+    run(t, x, jax.random.key(4))
+    assert [e["kind"] for e in t.log.entries][1:] == \
+        (["model_weight"] if extra else [])
+    assert t.log.total_bits == rung_bits((codec,), shape, extra)[0]
+    assert t.log.entries[0]["bits"] == rung_bits((codec,), shape)[0]
 
 
 # =============================================================== TransportLog

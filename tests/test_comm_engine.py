@@ -181,6 +181,47 @@ def test_replay_books_the_scan_rows(blob, name):
         assert tc.skipped
 
 
+ASYNC_REPLAY_RUNS = {
+    # channel-less: the per-agent mid-merge pairs, raw
+    "plain": MeteredTransport,
+    # an int8 barrier: raw alphas, then one encoded release per round
+    "int8": lambda: MeteredTransport(codec=make_codec("int8")),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ASYNC_REPLAY_RUNS))
+def test_async_replay_books_the_barrier_rows(blob, name):
+    """The folded replay books the eager async-stale ledger, and the head's
+    freshest IgnoranceMsg carries, bit for bit, the barrier release
+    ``w_bar[t]`` (channel-less: the last agent's mid-merge snapshot
+    ``w_trace[t, m]``) of the last round that shipped one, as its own
+    device array."""
+    Xtr, ctr, _, _, k = blob
+    cfg = SessionConfig(num_classes=k, max_rounds=3)
+    runs = {}
+    for backend in ("eager", "compiled"):
+        transport = ASYNC_REPLAY_RUNS[name]()
+        eps = endpoints_for([LogisticRegression(steps=40) for _ in Xtr], Xtr)
+        proto = Protocol(cfg, scheduler=AsyncStaleScheduler(),
+                         transport=transport, backend=backend)
+        proto.fit(jax.random.key(11), eps, ctr)
+        runs[backend] = proto, transport, eps
+    proto, tc, eps = runs["compiled"]
+    assert tc.log.entries == runs["eager"][1].log.entries
+    res = proto.compiled_result
+    if name == "plain":
+        m = len(eps) - 1                   # the agent whose hop ends at 0
+        t = int(np.flatnonzero(np.asarray(res.valid)[:, m])[-1])
+        want = res.w_trace[t, m]
+    else:
+        t = int(np.flatnonzero(np.asarray(res.sent))[-1])
+        want = res.w_bar[t]
+    msg = eps[0].latest("ignorance")
+    assert msg.src == ("barrier" if name == "int8" else eps[m].name)
+    assert isinstance(msg.w, jax.Array)
+    assert np.asarray(msg.w).tobytes() == np.asarray(want).tobytes()
+
+
 def test_budget_per_link_cap(blob):
     """A per-link cap starves each link independently of the session cap."""
     n = blob[0][0].shape[0]

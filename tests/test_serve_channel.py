@@ -214,7 +214,7 @@ def _squeeze_serve_budget(transport, spec, shape, leave_rungs):
     """Shrink the remaining session budget (via the resume carryover
     mechanism) so the next predict can afford exactly the cheapest
     ``leave_rungs`` serve blocks."""
-    costs = spec.serve_costs(shape)
+    costs = spec.payload_costs(shape)
     transport.carryover_bits = (spec.session_bits - transport.log.total_bits
                                 - costs[-1] * leave_rungs)
 
